@@ -594,7 +594,6 @@ def sharded_grouped_fn(mesh, r_max: int, backend: str, method: str,
     key = (mesh, r_max, backend, method, tuple(axes))
     if key in _SHARDED_FN_CACHE:
         return _SHARDED_FN_CACHE[key]
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     axes = tuple(axes)
     axis_sizes = tuple(mesh.shape[a] for a in axes)
@@ -608,9 +607,9 @@ def sharded_grouped_fn(mesh, r_max: int, backend: str, method: str,
         gb = None if global_bs is None else jnp.stack(global_bs)
         ga = None if global_as is None else jnp.stack(global_as)
         cl = client_spec(axes)
-        red = shard_map(partial_fn, mesh=mesh,
-                        in_specs=(cl, cl, cl, P(), P()),
-                        out_specs=P(), check_rep=False)(
+        red = jax.shard_map(partial_fn, mesh=mesh,
+                            in_specs=(cl, cl, cl, P(), P()),
+                            out_specs=P(), check_vma=False)(
             group_bs, group_as, group_w, gb, ga)
         if method in ("fedavg", "hetlora", "ffa"):
             b_g, a_g = red
@@ -627,9 +626,14 @@ def sharded_grouped_fn(mesh, r_max: int, backend: str, method: str,
             if backend == "kernel":
                 # (R, R) Gram cores of the reduced, replicated stack via
                 # the Pallas grids, then the Gram-core realloc -- the same
-                # math as the single-host kernel path (DESIGN.md §4.3)
+                # math as the single-host kernel path (DESIGN.md §4.3).
+                # A Mosaic kernel cannot be partitioned by XLA, so every
+                # device runs the grids on its own replicated copy.
                 from repro.kernels import ops as kernel_ops
-                g_u, g_v = kernel_ops.factored_gram_lead(u_c, v_c)
+                g_u, g_v = jax.shard_map(
+                    kernel_ops.factored_gram_lead, mesh=mesh,
+                    in_specs=(P(), P()), out_specs=(P(), P()),
+                    check_vma=False)(u_c, v_c)
                 b_g, a_g, sigma = _realloc_gram_lead(u_c, v_c, g_u, g_v,
                                                      r_max)
             else:

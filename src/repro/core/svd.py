@@ -43,6 +43,16 @@ def check_fallback_globals(fallback, global_b, global_a) -> None:
             "uncovered rank partitions can retain their global slices")
 
 
+def _whole(x: jnp.ndarray) -> jnp.ndarray:
+    """Gather an operand whose matrix axes carry an explicit sharding:
+    the decompositions (QR, SVD) take their matrix axes whole."""
+    sharding = jax.typeof(x).sharding
+    if any(ax is not None for ax in sharding.spec):
+        return jax.sharding.reshard(
+            x, sharding.update(spec=jax.sharding.PartitionSpec()))
+    return x
+
+
 def svd_realloc_dense(dw: jnp.ndarray, r_max: int
                       ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Paper-faithful: SVD the dense aggregate. Returns (B_g, A_g, sigma).
@@ -61,9 +71,10 @@ def svd_realloc_factored(u_c: jnp.ndarray, v_c: jnp.ndarray, r_max: int
     u_c (d, R); v_c (R, n). Returns (B_g (d, r_max), A_g (r_max, n), sigma).
     If R < r_max the trailing singular values are exactly zero and the
     factors are zero-padded (the aggregate has algebraic rank <= R).
+    Stacks sharded on an explicit mesh axis are gathered first.
     """
-    u_c = u_c.astype(jnp.float32)
-    v_c = v_c.astype(jnp.float32)
+    u_c = _whole(u_c.astype(jnp.float32))
+    v_c = _whole(v_c.astype(jnp.float32))
     q_u, r_u = jnp.linalg.qr(u_c)            # (d, R), (R, R)
     q_v, r_v = jnp.linalg.qr(v_c.T)          # (n, R), (R, R)
     core = r_u @ r_v.T                        # (R, R)

@@ -212,16 +212,15 @@ class LocalTrainer:
         Cache keys on (steps, mesh); jit re-specializes per shard size."""
         key = ("sharded", steps, mesh)
         if key not in self._vstep_cache:
-            from jax.experimental.shard_map import shard_map
             from repro.sharding.specs import round_engine_specs
             run = self._masked_run_fn(steps)
             spec = round_engine_specs()
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 run, mesh=mesh,
                 in_specs=(spec.replicated, spec.replicated, spec.batch_stack,
                           spec.replicated, spec.clients, spec.clients),
                 out_specs=(spec.clients, spec.clients),
-                check_rep=False)
+                check_vma=False)
             self._vstep_cache[key] = jax.jit(sharded)
         return self._vstep_cache[key]
 

@@ -1,9 +1,12 @@
 """Reusable end-to-end FedLoRA experiment setup (paper Section 6 proxy).
 
-Builds the synthetic-classification federated task: a reduced ViT-style
-encoder (patch-embedding frontend, class logit read from position 0),
-non-IID client shards, heterogeneous ranks, and a FederatedLoRA server for
-any aggregation method. All the accuracy/energy benchmarks and the
+Builds the synthetic-classification federated task: a ViT-style encoder
+(patch-embedding frontend, class logit read from position 0), non-IID
+client shards, heterogeneous ranks, and a FederatedLoRA server for any
+aggregation method. The encoder is either the tiny CPU-scale proxy
+(default) or a registered vision config at its published widths
+(``arch="vit-base"``), whose width, patch count and class count then shape
+the synthetic data too. All the accuracy/energy benchmarks and the
 integration tests run through this single harness, mirroring how every
 paper experiment shares one training pipeline.
 """
@@ -17,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.configs import get_config
 from repro.configs.base import (ACT_GELU, ATTN_BIDIR, FLConfig,
                                 FrontendConfig, LoRAConfig, ModelConfig)
 from repro.data import ClusterClassification, batches, make_partition
@@ -50,6 +54,17 @@ def fedvit_config(d_model: int = 128, num_layers: int = 2,
     )
 
 
+# host bytes the synthetic training set may take (float32 patch embeddings)
+HOST_DATA_BYTES = 2**30
+
+
+def _samples_per_class(num_classes: int, patches: int, dim: int) -> int:
+    """100 per class, or fewer where that would pass ``HOST_DATA_BYTES``
+    (vit-base widths: 17 per class, 1.03 GB)."""
+    return max(1, min(100, HOST_DATA_BYTES // (num_classes * patches * dim
+                                               * 4)))
+
+
 def _to_batch(x: np.ndarray, y: np.ndarray, num_positions: int) -> dict:
     """Classification batch: label read out at position 0.
 
@@ -80,13 +95,14 @@ class FLExperiment:
 
 
 def build_experiment(method: str = "raflora", *,
+                     arch: Optional[str] = None,
                      fl_overrides: Optional[dict] = None,
                      lora_overrides: Optional[dict] = None,
                      num_classes: int = 20,
                      d_model: int = 128,
                      modes_per_class: int = 4,
                      noise: float = 0.6,
-                     samples_per_class: int = 100,
+                     samples_per_class: Optional[int] = None,
                      batches_per_round: int = 2,
                      backend: str = "factored",
                      partial_up_to: Optional[int] = None,
@@ -99,13 +115,30 @@ def build_experiment(method: str = "raflora", *,
                      event_scheduler=None,
                      transport=None,
                      data_seed: int = 0) -> FLExperiment:
-    """``event_scheduler``: an ``events.EventScheduler`` switching the
+    """``arch``: a registered vision config (e.g. ``"vit-base"``) trained
+    at its published widths: the data takes the config's embedding width,
+    patch count and class count (``num_classes``/``d_model`` are then
+    ignored), and the client step recomputes each layer in the backward
+    pass (remat) -- without it a vit-base round of 5 clients x 32 items
+    needs ~80 GB of device memory. None: the tiny CPU-scale proxy.
+
+    ``samples_per_class``: None takes ``_samples_per_class``.
+
+    ``event_scheduler``: an ``events.EventScheduler`` switching the
     async engine from the fixed ``pipeline_depth`` cadence to arrival-event
     buffer triggers on the virtual clock (DESIGN.md §7).
 
     ``transport``: a ``transport.UpdateTransport``/``TransportConfig``
     compressing client factor uploads (int8/bf16 + error feedback,
     DESIGN.md §12); None ships f32."""
+    if arch is None:
+        cfg = fedvit_config(d_model=d_model, num_classes=num_classes)
+    else:
+        cfg = get_config(arch)
+        assert cfg.frontend.kind == "vision", \
+            f"{arch}: the federated task needs a vision frontend"
+    patches, dim = cfg.frontend.tokens_per_item, cfg.frontend.embed_dim
+    num_classes = cfg.vocab_size
     fl = FLConfig(aggregator=method, num_clients=20, participation=0.25,
                   num_rounds=40, local_batch_size=32, learning_rate=2e-3,
                   partition="pathological", dirichlet_alpha=1.0,
@@ -117,8 +150,10 @@ def build_experiment(method: str = "raflora", *,
     if lora_overrides:
         lora = dataclasses.replace(lora, **lora_overrides)
 
+    if samples_per_class is None:
+        samples_per_class = _samples_per_class(num_classes, patches, dim)
     data = ClusterClassification(
-        num_classes=num_classes, dim=d_model, patches=8,
+        num_classes=num_classes, dim=dim, patches=patches,
         modes_per_class=modes_per_class, noise=noise,
         samples_per_class=samples_per_class, seed=data_seed)
     (x_tr, y_tr), (x_te, y_te) = data.train_test_split()
@@ -126,9 +161,7 @@ def build_experiment(method: str = "raflora", *,
                             alpha=fl.dirichlet_alpha,
                             labels_per_client=fl.labels_per_client,
                             seed=fl.seed)
-    cfg = fedvit_config(d_model=d_model, num_classes=num_classes,
-                        patches=data.patches)
-    model = Model(cfg, lora, dtype=jnp.float32, remat=False,
+    model = Model(cfg, lora, dtype=jnp.float32, remat=arch is not None,
                   block_q=64, block_kv=64)
     registry = ClientRegistry.create(fl, lora, shards)
 

@@ -23,13 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -105,11 +99,9 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     grid = (b * kvh, n_q, n_kv)
     rows = g * block_q
-    scratch = ([_VMEM((rows, d), jnp.float32), _VMEM((rows,), jnp.float32),
-                _VMEM((rows,), jnp.float32)] if _VMEM is not None else
-               [jax.ShapeDtypeStruct((rows, d), jnp.float32),
-                jax.ShapeDtypeStruct((rows,), jnp.float32),
-                jax.ShapeDtypeStruct((rows,), jnp.float32)])
+    scratch = [pltpu.VMEM((rows, d), jnp.float32),
+               pltpu.VMEM((rows,), jnp.float32),
+               pltpu.VMEM((rows,), jnp.float32)]
     kernel = functools.partial(
         _kernel, causal=causal, window=window, block_q=block_q,
         block_kv=block_kv, n_kv=n_kv, kv_len=lkv, groups=g)

@@ -29,17 +29,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.rank_partition_agg import _pad_axis
 
-try:  # TPU-specific memory spaces; fall back gracefully off-TPU
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-
 _HI = jax.lax.Precision.HIGHEST
+# (M, N, K) grid: output tiles are independent, K carries the accumulators
+_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+# contract the last dims of both operands: x (m, k) . a (r, k) -> (m, r)
+_NT = (((1,), (1,)), ((), ()))
 
 
 def _kernel(x_ref, w_ref, a_ref, b_ref, o_ref, acc_ref, z_ref, *,
@@ -55,13 +54,13 @@ def _kernel(x_ref, w_ref, a_ref, b_ref, o_ref, acc_ref, z_ref, *,
     w = w_ref[...].astype(jnp.float32)          # (bk, bn)
     a = a_ref[...].astype(jnp.float32)          # (r, bk)
     acc_ref[...] += jax.lax.dot(x, w, precision=_HI)
-    z_ref[...] += jax.lax.dot(x, a.T, precision=_HI)
+    z_ref[...] += jax.lax.dot_general(x, a, _NT, precision=_HI)
 
     @pl.when(k == k_steps - 1)
     def _finalize():
         b = b_ref[...].astype(jnp.float32)      # (bn, r)
-        out = acc_ref[...] + scale * jax.lax.dot(
-            z_ref[...], b.T, precision=_HI)
+        out = acc_ref[...] + scale * jax.lax.dot_general(
+            z_ref[...], b, _NT, precision=_HI)
         o_ref[...] = out.astype(o_ref.dtype)
 
 
@@ -90,12 +89,8 @@ def lora_apply_pallas(x: jnp.ndarray, w: jnp.ndarray, a: jnp.ndarray,
     k_steps = kp // bk
     grid = (mp // bm, np_ // bn, k_steps)
 
-    if _VMEM is not None:
-        scratch_shapes = [_VMEM((bm, bn), jnp.float32),
-                          _VMEM((bm, r), jnp.float32)]
-    else:  # pragma: no cover
-        scratch_shapes = [jax.ShapeDtypeStruct((bm, bn), jnp.float32),
-                          jax.ShapeDtypeStruct((bm, r), jnp.float32)]
+    scratch_shapes = [pltpu.VMEM((bm, bn), jnp.float32),
+                      pltpu.VMEM((bm, r), jnp.float32)]
 
     kernel = functools.partial(_kernel, scale=scale, k_steps=k_steps)
     out = pl.pallas_call(
@@ -110,9 +105,7 @@ def lora_apply_pallas(x: jnp.ndarray, w: jnp.ndarray, a: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=scratch_shapes,
-        compiler_params=dict(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ) if _VMEM is not None and not interpret else None,
+        compiler_params=_SEMANTICS,
         interpret=interpret,
     )(xp, wp, ap, bp)
     return out[:m, :n]
@@ -122,14 +115,14 @@ def lora_apply_pallas(x: jnp.ndarray, w: jnp.ndarray, a: jnp.ndarray,
 # batched multi-adapter kernel (serving path, DESIGN.md §11)
 # ---------------------------------------------------------------------------
 
-def _batched_kernel(pages_ref, x_ref, w_ref, a_ref, b_ref, s_ref, o_ref,
+def _batched_kernel(pages_ref, scales_ref, x_ref, w_ref, a_ref, b_ref, o_ref,
                     acc_ref, z_ref, *, k_steps: int):
     """One (row-block, n-block) output tile whose rows all share the page
     selected by the scalar-prefetched ``pages_ref`` -- the A/B BlockSpec
     index maps gather that page's factors straight from the cache, so the
     rank-r bottleneck z stays VMEM-resident per tile exactly as in the
-    single-adapter kernel."""
-    k = pl.program_id(2)
+    single-adapter kernel. The page scales are prefetched scalars too."""
+    i, k = pl.program_id(0), pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
@@ -140,13 +133,13 @@ def _batched_kernel(pages_ref, x_ref, w_ref, a_ref, b_ref, s_ref, o_ref,
     w = w_ref[...].astype(jnp.float32)          # (bk, bn)
     a = a_ref[0].astype(jnp.float32)            # (r, bk): this block's page
     acc_ref[...] += jax.lax.dot(x, w, precision=_HI)
-    z_ref[...] += jax.lax.dot(x, a.T, precision=_HI)
+    z_ref[...] += jax.lax.dot_general(x, a, _NT, precision=_HI)
 
     @pl.when(k == k_steps - 1)
     def _finalize():
         b = b_ref[0].astype(jnp.float32)        # (bn, r)
-        out = acc_ref[...] + s_ref[0] * jax.lax.dot(
-            z_ref[...], b.T, precision=_HI)
+        out = acc_ref[...] + scales_ref[pages_ref[i]] * jax.lax.dot_general(
+            z_ref[...], b, _NT, precision=_HI)
         o_ref[...] = out.astype(o_ref.dtype)
 
 
@@ -185,37 +178,26 @@ def batched_lora_apply_pallas(x: jnp.ndarray, w: jnp.ndarray,
     k_steps = kp // bk
     grid = (m // bm, np_ // bn, k_steps)
 
-    if _VMEM is not None:
-        scratch_shapes = [_VMEM((bm, bn), jnp.float32),
-                          _VMEM((bm, r), jnp.float32)]
-    else:  # pragma: no cover
-        scratch_shapes = [jax.ShapeDtypeStruct((bm, bn), jnp.float32),
-                          jax.ShapeDtypeStruct((bm, r), jnp.float32)]
-
     kernel = functools.partial(_batched_kernel, k_steps=k_steps)
-    if pltpu is None:  # pragma: no cover - non-TPU builds lack prefetch
-        raise NotImplementedError("batched lora kernel needs pallas-tpu")
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk, pg: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk, pg: (kk, j)),
-            pl.BlockSpec((1, r, bk), lambda i, j, kk, pg: (pg[i], 0, kk)),
-            pl.BlockSpec((1, bn, r), lambda i, j, kk, pg: (pg[i], j, 0)),
-            pl.BlockSpec((1,), lambda i, j, kk, pg: (pg[i],)),
+            pl.BlockSpec((bm, bk), lambda i, j, kk, pg, sc: (i, kk)),
+            pl.BlockSpec((bk, bn), lambda i, j, kk, pg, sc: (kk, j)),
+            pl.BlockSpec((1, r, bk), lambda i, j, kk, pg, sc: (pg[i], 0, kk)),
+            pl.BlockSpec((1, bn, r), lambda i, j, kk, pg, sc: (pg[i], j, 0)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, pg: (i, j)),
-        scratch_shapes=scratch_shapes,
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, pg, sc: (i, j)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
+                        pltpu.VMEM((bm, r), jnp.float32)],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, np_), x.dtype),
-        compiler_params=dict(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ) if not interpret else None,
+        compiler_params=_SEMANTICS,
         interpret=interpret,
-    )(block_pages.astype(jnp.int32), xp, wp, ap, bp,
-      scales.astype(jnp.float32))
+    )(block_pages.astype(jnp.int32), scales.astype(jnp.float32), xp, wp,
+      ap, bp)
     return out[:, :n]

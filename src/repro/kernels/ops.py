@@ -1,8 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
 Each op pads to hardware-friendly shapes, dispatches to the kernel (interpret
-mode on CPU -- the kernel body runs in Python for correctness validation;
-compiled Mosaic on real TPU), and slices back. Oracles in ``ref.py``.
+mode off the TPU -- the kernel body runs in Python for correctness
+validation; compiled Mosaic on a TPU backend, never interpreted there), and
+slices back. Oracles in ``ref.py``.
 """
 from __future__ import annotations
 
@@ -15,7 +16,8 @@ import jax.numpy as jnp
 from repro.core.svd import check_fallback_globals
 from repro.kernels.lora_apply import (batched_lora_apply_pallas,
                                       lora_apply_pallas)
-from repro.kernels.rank_partition_agg import (gram_left_layered_pallas,
+from repro.kernels.rank_partition_agg import (GRAM_SINGLE_BLOCK_MAX,
+                                              gram_left_layered_pallas,
                                               gram_right_layered_pallas,
                                               rank_partition_agg_layered_pallas,
                                               rank_partition_agg_pallas,
@@ -23,8 +25,13 @@ from repro.kernels.rank_partition_agg import (gram_left_layered_pallas,
                                               weighted_stack_b_layered_pallas)
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
-_ON_TPU = jax.default_backend() == "tpu"
-_INTERPRET = not _ON_TPU
+
+def _interpret() -> bool:
+    """Interpret the kernels unless the backend is a TPU. Asked when an op
+    TRACES, never at import: importing this module must not start a
+    backend (that would take the chip), and one process may trace for
+    more than one backend."""
+    return jax.default_backend() != "tpu"
 
 
 # pad-to-multiple: the ONE zero-pad helper, shared with the kernel grids
@@ -57,7 +64,7 @@ def lora_apply(x: jnp.ndarray, w: jnp.ndarray, a: jnp.ndarray,
                           block_m=min(256, xp.shape[0]),
                           block_n=min(512, wp.shape[1]),
                           block_k=min(512, xp.shape[1]),
-                          interpret=_INTERPRET)
+                          interpret=_interpret())
     return y[:m, :n].reshape(lead + (n,)).astype(x.dtype)
 
 
@@ -110,7 +117,7 @@ def batched_lora_apply(x: jnp.ndarray, w: jnp.ndarray,
     y_g = batched_lora_apply_pallas(
         xp, wp, ap, bp, scales, block_page,
         block_m=bm, block_n=min(512, wp.shape[1]),
-        block_k=min(512, xp.shape[1]), interpret=_INTERPRET)
+        block_k=min(512, xp.shape[1]), interpret=_interpret())
     y2 = jnp.zeros((m, n), x.dtype).at[order].set(y_g[dest, :n])
     return y2.reshape(lead + (n,))
 
@@ -136,7 +143,7 @@ def rank_partition_agg(bs: jnp.ndarray, as_: jnp.ndarray, omega: jnp.ndarray,
     return rank_partition_agg_pallas(
         bsp, asp, omp,
         block_d=_tile_block(bsp.shape[1]), block_n=_tile_block(asp.shape[2]),
-        interpret=_INTERPRET)
+        interpret=_interpret())
 
 
 @jax.jit
@@ -164,7 +171,7 @@ def rank_partition_agg_layered(bs: jnp.ndarray, as_: jnp.ndarray,
     return rank_partition_agg_layered_pallas(
         bsp, asp, omp,
         block_d=_tile_block(bsp.shape[2]), block_n=_tile_block(asp.shape[3]),
-        interpret=_INTERPRET)
+        interpret=_interpret())
 
 
 # -- fused factored aggregation (DESIGN.md §4.3): O((d+n)R) memory ----------
@@ -220,23 +227,26 @@ def factored_stack_layered(bs: jnp.ndarray, as_: jnp.ndarray,
     asp = _pad_to(as_, 2, 8)
     omp = _pad_to(omega, 1, 8)
     u_c = weighted_stack_b_layered_pallas(
-        bsp, omp, block_d=_tile_block(bsp.shape[2]), interpret=_INTERPRET)
+        bsp, omp, block_d=_tile_block(bsp.shape[2]), interpret=_interpret())
     v_c = weighted_stack_a_layered_pallas(
-        asp, omp, block_n=_tile_block(asp.shape[3]), interpret=_INTERPRET)
+        asp, omp, block_n=_tile_block(asp.shape[3]), interpret=_interpret())
     return u_c, v_c
 
 
 def factored_gram_layered(u_c: jnp.ndarray, v_c: jnp.ndarray
                           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """u_c (L, d, R); v_c (L, R, n) -> Gram cores (L, R, R) x2. R is padded
-    to 8 so the core tiles; callers slice back to the incoming width."""
+    to 8 so the core tiles (to 128 past ``GRAM_SINGLE_BLOCK_MAX``, where the
+    core no longer fits one block); callers slice back to the incoming
+    width. Zero columns are spectrum-inert."""
     rr = u_c.shape[-1]
-    up = _pad_to(u_c, 2, 8)
-    vp = _pad_to(v_c, 1, 8)
+    mult = 8 if rr <= GRAM_SINGLE_BLOCK_MAX else 128
+    up = _pad_to(u_c, 2, mult)
+    vp = _pad_to(v_c, 1, mult)
     g_u = gram_left_layered_pallas(up, block_d=_tile_block(up.shape[1]),
-                                   interpret=_INTERPRET)
+                                   interpret=_interpret())
     g_v = gram_right_layered_pallas(vp, block_n=_tile_block(vp.shape[2]),
-                                    interpret=_INTERPRET)
+                                    interpret=_interpret())
     return g_u[:, :rr, :rr], g_v[:, :rr, :rr]
 
 
@@ -333,18 +343,23 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, a_log: jnp.ndarray,
     assert L % chunk == 0
     nc = L // chunk
     reps = H // G
-    bh = jnp.repeat(b, reps, axis=2).reshape(B_, nc, chunk, H, N)
-    ch = jnp.repeat(c, reps, axis=2).reshape(B_, nc, chunk, H, N)
-    xr = x.reshape(B_, nc, chunk, H, P)
-    dtr = dt.reshape(B_, nc, chunk, H)
+
+    def head_major(t):   # (B, L, H, F) -> (B, H, nc, chunk, F)
+        return jnp.moveaxis(t.reshape(B_, nc, chunk, H, t.shape[-1]), 3, 1)
+
+    dtf = dt.astype(jnp.float32)[..., None]                       # (B,L,H,1)
+    a_neg = -jnp.exp(a_log.astype(jnp.float32))                   # (H,)
+    cum = jnp.cumsum(head_major(dtf * a_neg[:, None]), axis=3)    # in-chunk
     init = (jnp.zeros((B_, H, P, N), jnp.float32)
             if init_state is None else init_state.astype(jnp.float32))
-    block_heads = 8 if H % 8 == 0 else (4 if H % 4 == 0 else 1)
-    y, final = ssd_scan_pallas(xr, dtr, a_log.astype(jnp.float32), bh, ch,
-                               d_skip.astype(jnp.float32), init,
-                               block_heads=block_heads,
-                               interpret=_INTERPRET)
-    return y.reshape(B_, L, H, P), final
+    y, final = ssd_scan_pallas(
+        head_major(x), head_major(dtf), cum,
+        head_major(jnp.repeat(b, reps, axis=2)),
+        head_major(jnp.repeat(c, reps, axis=2)), init,
+        interpret=_interpret())
+    y = jnp.moveaxis(y, 1, 3).reshape(B_, L, H, P)
+    skip = x.astype(jnp.float32) * d_skip.astype(jnp.float32)[:, None]
+    return (y + skip).astype(x.dtype), final
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window"))
@@ -361,5 +376,5 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     vp = _pad_to(v, 1, bk)
     out = flash_attention_pallas(qp, kp, vp, causal=causal, window=window,
                                  block_q=bq, block_kv=bk,
-                                 interpret=_INTERPRET)
+                                 interpret=_interpret())
     return out[:, :lq]

@@ -48,13 +48,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -70,17 +64,37 @@ def _pad_axis(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
 
 
 def _acc_scratch(shape):
-    return [_VMEM(shape, jnp.float32)] if _VMEM is not None else \
-        [jax.ShapeDtypeStruct(shape, jnp.float32)]
+    return [pltpu.VMEM(shape, jnp.float32)]
 
 
-def _block_div(dim: int, preferred: int) -> int:
-    """Largest tile <= preferred that divides ``dim`` (dims the callers
-    guarantee tile-able, e.g. the 8-padded R width)."""
-    b = min(preferred, dim)
-    while dim % b:
-        b -= 1
-    return b
+def _omega_rows(omega: jnp.ndarray) -> jnp.ndarray:
+    """(M, r) -> (M, 1, r): each client's weights as one (1, r) row block.
+
+    A TPU block's last two dims must tile by (8, 128) or span the array;
+    a (1, r) block of the (M, r) matrix does neither, a (1, 1, r) block of
+    the (M, 1, r) view spans both."""
+    return omega[:, None, :]
+
+
+def _omega_cols(omega: jnp.ndarray) -> jnp.ndarray:
+    """(M, r) -> (M, r, 1): the column view, for scaling the rows of A."""
+    return omega[:, :, None]
+
+
+# widest Gram core held as ONE (R, R) block when R does not tile by 128:
+# its f32 accumulator, double-buffered output and (256, R) input blocks
+# then stay near 5 MB of VMEM; wider cores must be padded to 128
+GRAM_SINGLE_BLOCK_MAX = 512
+
+
+def _gram_block(rr: int, preferred: int) -> int:
+    """Core tile for an R-wide Gram: ``preferred`` (a multiple of 128)
+    when it divides R, else the whole R -- a TPU block's lane dim must
+    tile by 128 or span the array."""
+    if rr % preferred == 0:
+        return preferred
+    assert rr <= GRAM_SINGLE_BLOCK_MAX, (rr, preferred)
+    return rr
 
 
 # ---------------------------------------------------------------------------
@@ -96,8 +110,8 @@ def _kernel(bs_ref, as_ref, om_ref, o_ref, acc_ref, *, m_steps: int):
 
     b = bs_ref[0].astype(jnp.float32)            # (bd, r)
     a = as_ref[0].astype(jnp.float32)            # (r, bn)
-    om = om_ref[0].astype(jnp.float32)           # (r,)
-    acc_ref[...] += jax.lax.dot(b * om[None, :], a, precision=_HI)
+    om = om_ref[0].astype(jnp.float32)           # (1, r)
+    acc_ref[...] += jax.lax.dot(b * om, a, precision=_HI)
 
     @pl.when(m == m_steps - 1)
     def _finalize():
@@ -124,13 +138,13 @@ def rank_partition_agg_pallas(bs: jnp.ndarray, as_: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((1, bd, r), lambda i, j, mm: (mm, i, 0)),
             pl.BlockSpec((1, r, bn), lambda i, j, mm: (mm, 0, j)),
-            pl.BlockSpec((1, r), lambda i, j, mm: (mm, 0)),
+            pl.BlockSpec((1, 1, r), lambda i, j, mm: (mm, 0, 0)),
         ],
         out_specs=pl.BlockSpec((bd, bn), lambda i, j, mm: (i, j)),
         out_shape=jax.ShapeDtypeStruct((dp, np_), jnp.float32),
         scratch_shapes=_acc_scratch((bd, bn)),
         interpret=interpret,
-    )(bs, as_, omega)
+    )(bs, as_, _omega_rows(omega))
     return out[:d, :n]
 
 
@@ -143,8 +157,8 @@ def _layered_kernel(bs_ref, as_ref, om_ref, o_ref, acc_ref, *, m_steps: int):
 
     b = bs_ref[0, 0].astype(jnp.float32)         # (bd, r)
     a = as_ref[0, 0].astype(jnp.float32)         # (r, bn)
-    om = om_ref[0].astype(jnp.float32)           # (r,)
-    acc_ref[...] += jax.lax.dot(b * om[None, :], a, precision=_HI)
+    om = om_ref[0].astype(jnp.float32)           # (1, r)
+    acc_ref[...] += jax.lax.dot(b * om, a, precision=_HI)
 
     @pl.when(m == m_steps - 1)
     def _finalize():
@@ -175,13 +189,13 @@ def rank_partition_agg_layered_pallas(bs: jnp.ndarray, as_: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((1, 1, bd, r), lambda ll, i, j, mm: (ll, mm, i, 0)),
             pl.BlockSpec((1, 1, r, bn), lambda ll, i, j, mm: (ll, mm, 0, j)),
-            pl.BlockSpec((1, r), lambda ll, i, j, mm: (mm, 0)),
+            pl.BlockSpec((1, 1, r), lambda ll, i, j, mm: (mm, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bd, bn), lambda ll, i, j, mm: (ll, i, j)),
         out_shape=jax.ShapeDtypeStruct((l, dp, np_), jnp.float32),
         scratch_shapes=_acc_scratch((bd, bn)),
         interpret=interpret,
-    )(bs, as_, omega)
+    )(bs, as_, _omega_rows(omega))
     return out[:, :d, :n]
 
 
@@ -191,8 +205,8 @@ def rank_partition_agg_layered_pallas(bs: jnp.ndarray, as_: jnp.ndarray,
 
 def _stack_b_kernel(bs_ref, om_ref, u_ref):
     b = bs_ref[0, 0].astype(jnp.float32)                        # (bd, r)
-    sq = jnp.sqrt(jnp.maximum(om_ref[0].astype(jnp.float32), 0.0))
-    u_ref[0] = (b * sq[None, :]).astype(u_ref.dtype)
+    sq = jnp.sqrt(jnp.maximum(om_ref[0].astype(jnp.float32), 0.0))  # (1, r)
+    u_ref[0, 0] = (b * sq).astype(u_ref.dtype)
 
 
 def weighted_stack_b_layered_pallas(bs: jnp.ndarray, omega: jnp.ndarray, *,
@@ -202,7 +216,9 @@ def weighted_stack_b_layered_pallas(bs: jnp.ndarray, omega: jnp.ndarray, *,
 
     Client m's weighted columns B_m diag(sqrt(omega_m)) land in column
     block m -- the left factor of DESIGN.md §4.2's U_c V_c form, built
-    on-chip so dW is never needed."""
+    on-chip so dW is never needed. The grid writes (L, M, d, r) -- an
+    (bd, r) block inside the M*r-wide stack would be a lane-unaligned
+    block -- and one XLA transpose interleaves the clients into columns."""
     l, m, d, r = bs.shape
     bd = min(block_d, d)
     bs = _pad_axis(bs, 2, bd)
@@ -213,19 +229,20 @@ def weighted_stack_b_layered_pallas(bs: jnp.ndarray, omega: jnp.ndarray, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bd, r), lambda ll, mm, t: (ll, mm, t, 0)),
-            pl.BlockSpec((1, r), lambda ll, mm, t: (mm, 0)),
+            pl.BlockSpec((1, 1, r), lambda ll, mm, t: (mm, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bd, r), lambda ll, mm, t: (ll, t, mm)),
-        out_shape=jax.ShapeDtypeStruct((l, dp, m * r), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, bd, r),
+                               lambda ll, mm, t: (ll, mm, t, 0)),
+        out_shape=jax.ShapeDtypeStruct((l, m, dp, r), jnp.float32),
         interpret=interpret,
-    )(bs, omega)
-    return out[:, :d]
+    )(bs, _omega_rows(omega))
+    return jnp.moveaxis(out[:, :, :d], 1, 2).reshape(l, d, m * r)
 
 
 def _stack_a_kernel(as_ref, om_ref, v_ref):
     a = as_ref[0, 0].astype(jnp.float32)                        # (r, bn)
-    sq = jnp.sqrt(jnp.maximum(om_ref[0].astype(jnp.float32), 0.0))
-    v_ref[0] = (a * sq[:, None]).astype(v_ref.dtype)
+    sq = jnp.sqrt(jnp.maximum(om_ref[0].astype(jnp.float32), 0.0))  # (r, 1)
+    v_ref[0] = (a * sq).astype(v_ref.dtype)
 
 
 def weighted_stack_a_layered_pallas(as_: jnp.ndarray, omega: jnp.ndarray, *,
@@ -242,12 +259,12 @@ def weighted_stack_a_layered_pallas(as_: jnp.ndarray, omega: jnp.ndarray, *,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, r, bn), lambda ll, mm, t: (ll, mm, 0, t)),
-            pl.BlockSpec((1, r), lambda ll, mm, t: (mm, 0)),
+            pl.BlockSpec((1, r, 1), lambda ll, mm, t: (mm, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, r, bn), lambda ll, mm, t: (ll, mm, t)),
         out_shape=jax.ShapeDtypeStruct((l, m * r, np_), jnp.float32),
         interpret=interpret,
-    )(as_, omega)
+    )(as_, _omega_cols(omega))
     return out[..., :n]
 
 
@@ -293,10 +310,11 @@ def gram_left_layered_pallas(u_c: jnp.ndarray, *, block_d: int = 256,
     d-step sum of (bd x br)^T @ (bd x br) MXU products in f32 scratch --
     upper-triangle blocks only (the Gram matrix is symmetric; the lower
     half is mirrored with one elementwise select, halving the MXU work).
-    R must tile by 8 (ops.py pads client ranks to 8)."""
+    R must tile by 8, and by 128 beyond ``GRAM_SINGLE_BLOCK_MAX`` (ops.py
+    pads)."""
     l, d, rr = u_c.shape
     bd = min(block_d, d)
-    br = _block_div(rr, block_r)
+    br = _gram_block(rr, block_r)
     u_c = _pad_axis(u_c, 1, bd)
     dp = u_c.shape[1]
     grid = (l, rr // br, rr // br, dp // bd)
@@ -323,7 +341,7 @@ def gram_right_layered_pallas(v_c: jnp.ndarray, *, block_n: int = 256,
     """v_c (L, R, n) -> G_v = V_c V_c^T (L, R, R) f32."""
     l, rr, n = v_c.shape
     bn = min(block_n, n)
-    br = _block_div(rr, block_r)
+    br = _gram_block(rr, block_r)
     v_c = _pad_axis(v_c, 2, bn)
     np_ = v_c.shape[2]
     grid = (l, rr // br, rr // br, np_ // bn)

@@ -5,20 +5,24 @@ recurrence into per-chunk matmuls -- exactly what the MXU wants -- plus a
 tiny sequential inter-chunk state update. A GPU implementation leans on
 warp-level associative scans; on TPU the right decomposition is:
 
-  grid = (batch, head-blocks, chunks), chunk axis innermost & sequential;
+  grid = (batch, heads, chunks), chunk axis innermost & sequential;
   per step:   cb   = C_q B_q^T             (Q x Q matmul, MXU)
-              y    = (cb * Lmat) X + (C decay) . state   (MXU)
-              state = chunk_decay * state + (B^T weighted X)  (MXU)
+              y    = (cb * Lmat) X + (C state^T) * decay   (MXU)
+              state = chunk_decay * state + (decayed X)^T B  (MXU)
 
-The (P x N) state for the head-block lives in VMEM scratch across the chunk
-loop; nothing recurrent ever round-trips HBM. Q (chunk) and N are 128-ish;
-P=64 (mamba2) -> tiles are MXU-aligned or padded by ops.py.
+One grid step holds one head's chunk as plain 2-D tiles, so every block's
+last two dims span the array (Q x P, Q x N, P x N) and every product is a
+2-D MXU matmul. The (P x N) state lives in VMEM scratch across the chunk
+loop; nothing recurrent ever round-trips HBM.
 
-Layout expected by the kernel (pre-reshaped by ops.py):
-  x   (B, nc, Q, H, P)        dt (B, nc, Q, H)
-  b,c (B, nc, Q, H, N)        -- groups already expanded to heads
-  a_log (H,), d_skip (H,)     init_state (B, H, P, N)
-Outputs: y (B, nc, Q, H, P); final_state (B, H, P, N).
+Layout expected by the kernel (pre-arranged head-major by ops.py):
+  x   (B, H, nc, Q, P)        dt (B, H, nc, Q, 1)
+  cum (B, H, nc, Q, 1) and its row view (B, H, nc, 1, Q): the inclusive
+      in-chunk cumsum of dt * A (A = -exp(a_log) < 0)
+  b,c (B, H, nc, Q, N)        -- groups already expanded to heads
+  init_state (B, H, P, N)
+Outputs: y (B, H, nc, Q, P) f32 WITHOUT the D-skip term (ops.py adds it);
+final_state (B, H, P, N).
 """
 from __future__ import annotations
 
@@ -27,99 +31,90 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+_NT = (((1,), (1,)), ((), ()))      # a (m, k) . b (n, k) -> (m, n)
+_TN = (((0,), (0,)), ((), ()))      # a (k, m) . b (k, n) -> (m, n)
 
 
-def _kernel(x_ref, dt_ref, alog_ref, b_ref, c_ref, dskip_ref, init_ref,
+def _kernel(x_ref, dt_ref, cum_ref, cumr_ref, b_ref, c_ref, init_ref,
             y_ref, final_ref, state_ref, *, nc: int, q: int):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
-        state_ref[...] = init_ref[0].astype(jnp.float32)    # (bh, P, N)
+        state_ref[...] = init_ref[0, 0].astype(jnp.float32)    # (P, N)
 
-    x = x_ref[0, 0].astype(jnp.float32)       # (Q, bh, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)     # (Q, bh)
-    bq = b_ref[0, 0].astype(jnp.float32)      # (Q, bh, N)
-    cq = c_ref[0, 0].astype(jnp.float32)      # (Q, bh, N)
-    alog = alog_ref[...].astype(jnp.float32)  # (bh,)
-    a_neg = -jnp.exp(alog)                    # (bh,) < 0
-
-    a_inc = dt * a_neg[None, :]               # (Q, bh)
-    cum = jnp.cumsum(a_inc, axis=0)           # inclusive, (Q, bh)
-    dtx = x * dt[:, :, None]                  # (Q, bh, P)
+    x = x_ref[0, 0, 0].astype(jnp.float32)        # (Q, P)
+    dt = dt_ref[0, 0, 0].astype(jnp.float32)      # (Q, 1)
+    cum = cum_ref[0, 0, 0]                        # (Q, 1)
+    cum_row = cumr_ref[0, 0, 0]                   # (1, Q)
+    bq = b_ref[0, 0, 0].astype(jnp.float32)       # (Q, N)
+    cq = c_ref[0, 0, 0].astype(jnp.float32)       # (Q, N)
+    dtx = x * dt                                  # (Q, P)
 
     # intra-chunk: Lmat_ij = exp(cum_i - cum_j), i >= j (mask before exp --
     # see models/layers/ssd.py for the where-NaN rationale)
-    diff = cum[:, None, :] - cum[None, :, :]  # (Q, Q, bh)
     idx = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     jdx = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    causal = (idx >= jdx)[:, :, None]
-    lmat = jnp.exp(jnp.where(causal, diff, -1e30))          # (Q, Q, bh)
-    cb = jnp.einsum("ihn,jhn->ijh", cq, bq)                 # (Q, Q, bh)
-    y_intra = jnp.einsum("ijh,jhp->ihp", cb * lmat, dtx)    # (Q, bh, P)
+    lmat = jnp.exp(jnp.where(idx >= jdx, cum - cum_row, -1e30))   # (Q, Q)
+    cb = jax.lax.dot_general(cq, bq, _NT)                          # (Q, Q)
+    y_intra = jnp.dot(cb * lmat, dtx)                              # (Q, P)
 
-    # inter-chunk: contribution of carried state
-    state = state_ref[...]                                  # (bh, P, N)
-    decay_in = jnp.exp(cum)                                 # (Q, bh)
-    y_inter = jnp.einsum("qhn,hpn,qh->qhp", cq, state, decay_in)
+    # inter-chunk: contribution of the carried state
+    state = state_ref[...]                                         # (P, N)
+    y_inter = jax.lax.dot_general(cq, state, _NT) * jnp.exp(cum)   # (Q, P)
 
     # state update
-    decay_out = jnp.exp(cum[-1:, :] - cum)                  # (Q, bh)
-    new_contrib = jnp.einsum("qhn,qhp,qh->hpn", bq, dtx, decay_out)
-    chunk_decay = jnp.exp(cum[-1, :])                       # (bh,)
-    state_ref[...] = state * chunk_decay[:, None, None] + new_contrib
+    # the chunk total cum[q-1], read by a masked lane reduction (a (1, 1)
+    # slice at lane q-1 gets a layout Mosaic cannot broadcast from)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, q), 1)
+    last = jnp.sum(jnp.where(lane == q - 1, cum_row, 0.0), axis=1,
+                   keepdims=True)                                  # (1, 1)
+    decayed = dtx * jnp.exp(last - cum)                            # (Q, P)
+    state_ref[...] = (state * jnp.exp(last)
+                      + jax.lax.dot_general(decayed, bq, _TN))     # (P, N)
 
-    dskip = dskip_ref[...].astype(jnp.float32)              # (bh,)
-    y = y_intra + y_inter + x * dskip[None, :, None]
-    y_ref[0, 0] = y.astype(y_ref.dtype)
+    y_ref[0, 0, 0] = y_intra + y_inter
 
     @pl.when(ci == nc - 1)
     def _final():
-        final_ref[0] = state_ref[...]
+        final_ref[0, 0] = state_ref[...]
 
 
-def ssd_scan_pallas(x, dt, a_log, b, c, d_skip, init_state, *,
-                    block_heads: int = 8,
+def ssd_scan_pallas(x, dt, cum, b, c, init_state, *,
                     interpret: bool = True):
-    """Inputs pre-chunked & group-expanded (see module docstring)."""
-    B_, nc, q, h, p = x.shape
+    """Inputs pre-chunked, head-major & group-expanded (module docstring)."""
+    B_, h, nc, q, p = x.shape
     n = b.shape[-1]
-    bh = min(block_heads, h)
-    assert h % bh == 0, (h, bh)
-    grid = (B_, h // bh, nc)
-
-    scratch = [_VMEM((bh, p, n), jnp.float32)] if _VMEM is not None else \
-        [jax.ShapeDtypeStruct((bh, p, n), jnp.float32)]
+    grid = (B_, h, nc)
+    chunk = lambda bi, hi, ci: (bi, hi, ci, 0, 0)
+    state = lambda bi, hi, ci: (bi, hi, 0, 0)
 
     kernel = functools.partial(_kernel, nc=nc, q=q)
     y, final = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, q, bh, p), lambda bi, hi, ci: (bi, ci, 0, hi, 0)),
-            pl.BlockSpec((1, 1, q, bh), lambda bi, hi, ci: (bi, ci, 0, hi)),
-            pl.BlockSpec((bh,), lambda bi, hi, ci: (hi,)),
-            pl.BlockSpec((1, 1, q, bh, n), lambda bi, hi, ci: (bi, ci, 0, hi, 0)),
-            pl.BlockSpec((1, 1, q, bh, n), lambda bi, hi, ci: (bi, ci, 0, hi, 0)),
-            pl.BlockSpec((bh,), lambda bi, hi, ci: (hi,)),
-            pl.BlockSpec((1, bh, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, 1, 1, q, p), chunk),
+            pl.BlockSpec((1, 1, 1, q, 1), chunk),
+            pl.BlockSpec((1, 1, 1, q, 1), chunk),
+            pl.BlockSpec((1, 1, 1, 1, q), chunk),
+            pl.BlockSpec((1, 1, 1, q, n), chunk),
+            pl.BlockSpec((1, 1, 1, q, n), chunk),
+            pl.BlockSpec((1, 1, p, n), state),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, q, bh, p), lambda bi, hi, ci: (bi, ci, 0, hi, 0)),
-            pl.BlockSpec((1, bh, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, 1, 1, q, p), chunk),
+            pl.BlockSpec((1, 1, p, n), state),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B_, nc, q, h, p), x.dtype),
+            jax.ShapeDtypeStruct((B_, h, nc, q, p), jnp.float32),
             jax.ShapeDtypeStruct((B_, h, p, n), jnp.float32),
         ],
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dt, a_log, b, c, d_skip, init_state)
+    )(x, dt, cum, jnp.swapaxes(cum, -1, -2), b, c, init_state)
     return y, final

@@ -1,13 +1,15 @@
 """Federated fine-tuning driver (the end-to-end trainer).
 
 Runs heterogeneous-rank FedLoRA on the synthetic non-IID task with any of
-the five aggregation methods over any architecture family (reduced configs
-on CPU; the same code path scales to the production mesh via the sharding
-hooks in Model).
+the five aggregation methods, on the tiny CPU-scale ViT proxy (default) or
+on a registered vision config at its published widths (``--arch
+vit-base``, for a TPU).
 
   PYTHONPATH=src python -m repro.launch.train --method raflora --rounds 20
   PYTHONPATH=src python -m repro.launch.train --method flexlora --rounds 20 \
       --noniid dirichlet --alpha 0.1
+  PYTHONPATH=src python -m repro.launch.train --arch vit-base \
+      --backend kernel --rounds 3
 """
 from __future__ import annotations
 
@@ -19,6 +21,9 @@ import time
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--arch", default=None,
+                    help="registered vision config at published widths "
+                         "(e.g. vit-base); default: the tiny proxy")
     ap.add_argument("--method", default="raflora",
                     choices=["fedavg", "hetlora", "flora", "flexlora",
                              "raflora"])
@@ -38,9 +43,11 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from repro.federation.experiment import build_experiment
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     levels = tuple(int(r) for r in args.rank_levels.split(","))
     exp = build_experiment(
-        args.method,
+        args.method, arch=args.arch,
         fl_overrides={"num_rounds": args.rounds, "num_clients": args.clients,
                       "participation": args.participation,
                       "partition": args.noniid,

@@ -25,13 +25,6 @@ from repro.configs.base import MoEConfig
 from repro.models.layers.dense import dense_init
 from repro.models.layers.mlp import _act, is_gated, mlp_apply, mlp_init
 
-if hasattr(jax, "shard_map"):                      # jax >= 0.6
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:                                              # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-
 
 def moe_init(key, d_model: int, cfg: MoEConfig, activation: str, *,
              lora_ranks: dict, dtype=jnp.float32) -> dict:
@@ -183,13 +176,13 @@ def moe_apply_ep(params: dict, x: jnp.ndarray, cfg: MoEConfig,
         return combined.reshape(xt.shape), aux
 
     bspec = P(batch_axes, None, None)
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         block, mesh=mesh,
         in_specs=(bspec, P(), P(ep_axis, None, None),
                   P(ep_axis, None, None) if "w_gate" in params else P(),
                   P(ep_axis, None, None)),
         out_specs=(bspec, P()),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )(x, params["router"]["w"],
       params["w_up"], params.get("w_gate", jnp.zeros((0,))), params["w_down"])
     if "shared" in params:

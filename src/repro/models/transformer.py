@@ -151,8 +151,11 @@ class Model:
             params["lm_head"] = dense_init(k_head, cfg.d_model, cfg.vocab_size,
                                            dtype=dt)
         gks = jax.random.split(k_layers, self.num_groups)
-        groups = [self._group_init(gks[i], i) for i in range(self.num_groups)]
-        params["layers"] = jax.tree.map(lambda *xs: jnp.stack(xs), *groups)
+        # groups share one structure (the layer scan stacks them), so one
+        # group's init vmapped over the group keys gives the same values as
+        # initialising group by group, and a jitted init compiles one group
+        # instead of num_groups (minutes saved at 28+ layers on a TPU)
+        params["layers"] = jax.vmap(lambda k: self._group_init(k, 0))(gks)
         if self.lora is not None and self.lora.variant != "lora":
             params = self._apply_peft_variant(params)
         if cfg.frontend.kind != "none":

@@ -86,8 +86,24 @@ def seed_cache(cache, prefill_caches, prompt_len: int, slot_mask):
     return jax.tree_util.tree_map_with_path(merge, flat, got)
 
 
+def substitute_pages(base, pages, page_ids):
+    """Merge page-gathered per-slot factors over the base params: leaf
+    (P, G, ...) -> (S, G, ...) -> (G, S, ...), so the layer scan strips G
+    and ``dense_apply`` sees per-slot (S, ...) batched leaves."""
+    def gather(leaf):
+        if leaf is None:
+            return None
+        return jnp.moveaxis(leaf[page_ids], 0, 1)
+    lora = jax.tree.map(gather, pages, is_leaf=lambda x: x is None)
+    return merge_lora(base, lora)
+
+
 class ServingEngine:
-    """Fixed-slot multi-tenant engine over a published adapter snapshot."""
+    """Fixed-slot multi-tenant engine over a published adapter snapshot.
+
+    ``logits`` holds the (S, V) next-token logits of the last ``admit`` or
+    ``decode`` call, for checking the served path against a full forward.
+    """
 
     def __init__(self, model, params, store: AdapterStore, *,
                  max_len: int, slots: int):
@@ -107,31 +123,22 @@ class ServingEngine:
         self.cache["len"] = jnp.zeros((self.slots,), jnp.int32)
         self.tokens = jnp.zeros((self.slots,), jnp.int32)
         self.slot_pages = jnp.zeros((self.slots,), jnp.int32)
+        self.logits = None
         self.version_log: List[int] = []     # one snapshot version per step
 
-        def substituted(base, pages, page_ids):
-            """Merge page-gathered per-slot factors over the base params."""
-            def gather(leaf):
-                if leaf is None:
-                    return None
-                # (P, G, ...) -> (S, G, ...) -> (G, S, ...): the scan strips
-                # G and dense_apply sees per-slot (S, ...) batched leaves
-                return jnp.moveaxis(leaf[page_ids], 0, 1)
-            lora = jax.tree.map(gather, pages,
-                                is_leaf=lambda x: x is None)
-            return merge_lora(base, lora)
-
         def prefill_impl(base, pages, page_ids, prompts):
-            merged = substituted(base, pages, page_ids)
+            merged = substitute_pages(base, pages, page_ids)
             logits, caches = model.prefill(merged, {"tokens": prompts})
-            next_tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-            return next_tok, caches
+            logits = logits[:, -1, :]
+            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return next_tok, logits, caches
 
         def decode_impl(base, pages, page_ids, tokens, cache, active):
-            merged = substituted(base, pages, page_ids)
+            merged = substitute_pages(base, pages, page_ids)
             logits, new_cache = model.decode_step(
                 merged, {"token": tokens[:, None]}, cache)
-            next_tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+            logits = logits[:, -1, :]
+            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             # inactive slots are frozen: token, length and SSM states hold
             next_tok = jnp.where(active, next_tok, tokens)
             sel = lambda n, o: jax.tree.map(
@@ -141,7 +148,7 @@ class ServingEngine:
             new_cache["layers"] = sel(new_cache["layers"], cache["layers"])
             new_cache["len"] = jnp.where(active, new_cache["len"],
                                          cache["len"])
-            return next_tok, new_cache
+            return next_tok, logits, new_cache
 
         self._prefill = jax.jit(prefill_impl)
         self._decode = jax.jit(decode_impl)
@@ -164,8 +171,8 @@ class ServingEngine:
         full_prompts = full_prompts.at[jnp.asarray(slot_idx)].set(prompts)
         new_pages = self.slot_pages.at[jnp.asarray(slot_idx)].set(
             snap.page_ids(adapter_ids))
-        next_tok, caches = self._prefill(self.base, snap.pages, new_pages,
-                                         full_prompts)
+        next_tok, self.logits, caches = self._prefill(
+            self.base, snap.pages, new_pages, full_prompts)
         mask = jnp.zeros((self.slots,), bool).at[jnp.asarray(slot_idx)].set(
             True)
         self.cache = seed_cache(self.cache, caches, lp, mask)
@@ -179,7 +186,7 @@ class ServingEngine:
         snap = self.store.published            # THE capture
         self.version_log.append(snap.version)
         active = jnp.asarray(active_mask, bool)
-        self.tokens, self.cache = self._decode(
+        self.tokens, self.logits, self.cache = self._decode(
             self.base, snap.pages, self.slot_pages, self.tokens, self.cache,
             active)
         return self.tokens

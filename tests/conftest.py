@@ -2,25 +2,18 @@
 the single real CPU device; only launch/dryrun.py forces 512 devices (and
 ``tools/ci.sh shard-smoke`` forces 8 for the sharded round engine).
 
-The persistent XLA compilation cache (ROADMAP "Test wall time") is enabled
-for every test run: the federated integration tests dominate tier-1 wall
-time and their programs are identical across runs, so warm-cache runs skip
-most of the compile cost. Override the location with
-``JAX_COMPILATION_CACHE_DIR``; set it empty to disable."""
-import os
-
+The persistent XLA compilation cache is enabled for every test run, by the
+same helper the entry points use (``repro.launch.compile_cache``): the
+federated integration tests dominate tier-1 wall time and their programs
+are identical across runs, so warm-cache runs skip most of the compile
+cost. Override the location with ``JAX_COMPILATION_CACHE_DIR``; set it
+empty to disable."""
 import jax
-import jax.numpy as jnp
 import pytest
 
-_CACHE_DIR = os.environ.get(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                 ".jax_cache"))
-if _CACHE_DIR:
-    jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.25)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 
 # markers (incl. the ``slow`` tier deselected by ``tools/ci.sh smoke``)

@@ -161,13 +161,19 @@ def _adapter_products(server):
 class TestEngineMatrix:
     @pytest.mark.parametrize("mode", ["int8", "bf16"])
     def test_sequential_equals_batched(self, mode):
-        """Same encode order, same quantized bytes, same aggregation. int8
-        rounding is a step function, so the engines' differing f32 op order
-        (per-client loop vs stacked vmap) can flip single quantization
-        decisions -- agreement is to quantization-step tolerance, compared
-        on effective PRODUCTS (sign/rotation-invariant)."""
-        seq = _run("sequential", mode)
-        bat = _run("batched", mode)
+        """Same encode order, same quantized bytes, same aggregation, ONE
+        round from identical state: engine equivalence is a per-round
+        property -- over several rounds the truncated SVD's noise tail
+        amplifies f32 op-order differences chaotically (over 3 rounds, 7 of
+        2048 int8 product entries missed by 4.4e-4). int8 rounding is a
+        step function, so the engines' differing f32 op order (per-client
+        loop vs stacked vmap) could still flip a quantization decision
+        within the round. atol 2e-4 is ~3% of the largest product entry
+        (~6e-3 here): room for such a flip, far above one round's measured
+        difference (~5e-9). Compared on effective PRODUCTS
+        (sign/rotation-invariant)."""
+        seq = _run("sequential", mode, rounds=1)
+        bat = _run("batched", mode, rounds=1)
         np.testing.assert_allclose(seq.server.energy.higher_rank_ratio,
                                    bat.server.energy.higher_rank_ratio,
                                    rtol=5e-3, atol=5e-4)
