@@ -5,10 +5,12 @@ The device programs are certified by static analysis of their HLO; the
 computation, event scheduler) is plain numpy + Python and has no HLO to
 walk. This module gives it the same treatment with two signals:
 
-  * **loop iterations** -- federation code calls :func:`tick` at its
-    Python loops (one call per loop with ``n=len(...)``, so the hook adds
-    O(1) work per loop, not per element). Inactive monitors make ``tick``
-    a single global read -- the round path pays one ``is None`` check.
+  * **loop iterations** -- federation code counts its Python loops with
+    the program's one counter, :func:`repro.tracing.count` (one call per
+    loop with ``n=len(...)``, so the hook adds O(1) work per loop, not per
+    element). A monitor reads them as the counters' deltas between its
+    ``mark()`` calls; the compile counters (``compiles:*``) are not loops
+    and are left out.
   * **allocated ndarray bytes** -- while a :class:`HostCostMonitor` is
     active, a tracing shim patches the numpy array constructors
     (``np.zeros`` / ``np.asarray`` / ``np.stack`` / ...) on the numpy
@@ -37,6 +39,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro import tracing
+
 _ACTIVE: Optional["HostCostMonitor"] = None
 
 # numpy constructors worth tracing: everything the round path uses to
@@ -47,12 +51,9 @@ _TRACED_FNS = ("empty", "zeros", "ones", "full", "arange", "array",
                "copy", "pad", "where", "repeat", "tile")
 
 
-def tick(label: str, n: int = 1) -> None:
-    """Record ``n`` iterations of the host loop ``label`` (no-op unless a
-    monitor is active)."""
-    mon = _ACTIVE
-    if mon is not None:
-        mon.loop_iters[label] = mon.loop_iters.get(label, 0) + int(n)
+def _loop_counters() -> Dict[str, int]:
+    return {k: v for k, v in tracing.counters().items()
+            if not k.startswith(tracing.COMPILES)}
 
 
 def alloc(label: str, nbytes: int) -> None:
@@ -84,10 +85,11 @@ class HostCostMonitor:
     ``dispatch_audit.DispatchMonitor``)."""
 
     def __init__(self):
-        self.loop_iters: Dict[str, int] = {}
         self.alloc_bytes: Dict[str, int] = {}
         self.phases: List[HostPhase] = []
         self._last = (0, 0)
+        self._start_loops: Optional[Dict[str, int]] = None
+        self._end_loops: Optional[Dict[str, int]] = None
         self._last_loops: Dict[str, int] = {}
         self._saved: Dict[str, object] = {}
 
@@ -98,6 +100,8 @@ class HostCostMonitor:
             raise RuntimeError("nested HostCostMonitor")
         self._patch_numpy()
         _ACTIVE = self
+        self._start_loops = _loop_counters()
+        self._end_loops = None
         self._last = (0, 0)
         self._last_loops = {}
         return self
@@ -105,6 +109,7 @@ class HostCostMonitor:
     def __exit__(self, *exc) -> bool:
         global _ACTIVE
         _ACTIVE = None
+        self._end_loops = _loop_counters()
         for name, orig in self._saved.items():
             setattr(np, name, orig)
         self._saved.clear()
@@ -128,6 +133,18 @@ class HostCostMonitor:
             setattr(np, name, traced)
 
     # -- accounting --------------------------------------------------------
+    @property
+    def loop_iters(self) -> Dict[str, int]:
+        """Loop counts inside the monitor: the counters' deltas from its
+        entry to its exit (or to now, while it is open)."""
+        if self._start_loops is None:
+            return {}
+        start = self._start_loops
+        end = (self._end_loops if self._end_loops is not None
+               else _loop_counters())
+        return {k: v - start.get(k, 0) for k, v in end.items()
+                if v != start.get(k, 0)}
+
     @property
     def total_loop_iters(self) -> int:
         return sum(self.loop_iters.values())

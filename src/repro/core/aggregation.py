@@ -34,6 +34,7 @@ import numpy as np
 
 import functools
 
+from repro import tracing
 from repro.core import partitions as parts
 from repro.core.svd import (check_fallback_globals, dense_fallback_term,
                             dense_from_weighted, factored_append_fallback,
@@ -113,8 +114,7 @@ def staleness_discount(n_k: Sequence[float],
     exact no-ops (the input counts are returned unscaled), which is what
     makes ``pipeline_depth=1`` reduce bit-level to the batched engine.
     """
-    from repro.analysis import host_cost
-    host_cost.tick("agg/weight_counts", len(n_k))
+    tracing.count("agg/weight_counts", len(n_k))
     n = np.asarray(n_k, dtype=np.float64)
     if staleness is None or gamma == 1.0:
         return n
@@ -368,16 +368,18 @@ def _grouped_core(group_bs, group_as, warg, global_bs, global_as, fallback,
     (G, ..., d, r_group) arrays (group_as analogous); global_bs/global_as:
     tuples over bucket adapters of (..., d, r_max)/(..., r_max, n).
     Transport-quantized entries (QuantFactor) dequantize here, once, at
-    stack-build time.
+    stack-build time. The stack build is the named scope ``agg.stack``;
+    the factored realloc's are ``agg.qr`` and ``agg.core_svd``.
     """
-    bs = jnp.concatenate(
-        [_pad_rank(jnp.stack([_dq(b) for b in bt], axis=1), r_max, -1)
-         for bt in group_bs])                         # (M, P, ..., d, r_max)
-    as_ = jnp.concatenate(
-        [_pad_rank(jnp.stack([_dq(a) for a in at], axis=1), r_max, -2)
-         for at in group_as])                         # (M, P, ..., r_max, n)
-    gb = None if global_bs is None else jnp.stack(global_bs)
-    ga = None if global_as is None else jnp.stack(global_as)
+    with jax.named_scope("agg.stack"):
+        bs = jnp.concatenate(
+            [_pad_rank(jnp.stack([_dq(b) for b in bt], axis=1), r_max, -1)
+             for bt in group_bs])                     # (M, P, ..., d, r_max)
+        as_ = jnp.concatenate(
+            [_pad_rank(jnp.stack([_dq(a) for a in at], axis=1), r_max, -2)
+             for at in group_as])                     # (M, P, ..., r_max, n)
+        gb = None if global_bs is None else jnp.stack(global_bs)
+        ga = None if global_as is None else jnp.stack(global_as)
     return _dispatch_stacked(bs, as_, warg, gb, ga, fallback, r_max,
                              backend, method)
 

@@ -75,12 +75,14 @@ def svd_realloc_factored(u_c: jnp.ndarray, v_c: jnp.ndarray, r_max: int
     """
     u_c = _whole(u_c.astype(jnp.float32))
     v_c = _whole(v_c.astype(jnp.float32))
-    q_u, r_u = jnp.linalg.qr(u_c)            # (d, R), (R, R)
-    q_v, r_v = jnp.linalg.qr(v_c.T)          # (n, R), (R, R)
-    core = r_u @ r_v.T                        # (R, R)
-    u_s, s, vt_s = jnp.linalg.svd(core, full_matrices=False)
-    u_full = q_u @ u_s                        # (d, R)
-    vt_full = vt_s @ q_v.T                    # (R, n)
+    with jax.named_scope("agg.qr"):
+        q_u, r_u = jnp.linalg.qr(u_c)        # (d, R), (R, R)
+        q_v, r_v = jnp.linalg.qr(v_c.T)      # (n, R), (R, R)
+    with jax.named_scope("agg.core_svd"):
+        core = r_u @ r_v.T                    # (R, R)
+        u_s, s, vt_s = jnp.linalg.svd(core, full_matrices=False)
+        u_full = q_u @ u_s                    # (d, R)
+        vt_full = vt_s @ q_v.T                # (R, n)
     r = u_c.shape[1]
     if r >= r_max:
         u_full, s, vt_full = u_full[:, :r_max], s[:r_max], vt_full[:r_max]
@@ -130,12 +132,13 @@ def svd_realloc_gram(u_c: jnp.ndarray, v_c: jnp.ndarray,
         inv = jnp.where(keep, 1.0 / jnp.where(keep, jnp.sqrt(lam), 1.0), 0.0)
         return s, inv, p
 
-    s_u, inv_u, p_u = _whiten(g_u)
-    s_v, inv_v, p_v = _whiten(g_v)
-    core = (s_u[:, None] * (p_u.T @ p_v)) * s_v[None, :]      # (R, R)
-    w1, s, w2t = jnp.linalg.svd(core, full_matrices=False)
-    left = p_u @ (inv_u[:, None] * w1)                        # (R, R)
-    right = (w2t * inv_v[None, :]) @ p_v.T                    # (R, R)
+    with jax.named_scope("agg.core_svd"):
+        s_u, inv_u, p_u = _whiten(g_u)
+        s_v, inv_v, p_v = _whiten(g_v)
+        core = (s_u[:, None] * (p_u.T @ p_v)) * s_v[None, :]  # (R, R)
+        w1, s, w2t = jnp.linalg.svd(core, full_matrices=False)
+        left = p_u @ (inv_u[:, None] * w1)                    # (R, R)
+        right = (w2t * inv_v[None, :]) @ p_v.T                # (R, R)
     k = min(rr, r_max)
     b_g = (u_c @ left[:, :k]) * s[None, :k]                   # (d, k)
     a_g = right[:k] @ v_c                                     # (k, n)

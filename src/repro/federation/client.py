@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.lora import merge_lora, split_lora
 from repro.models.transformer import Model
 from repro.optim import AdamW
@@ -53,6 +54,15 @@ def _stack_steps(xs) -> "np.ndarray":
     if all(isinstance(x, np.ndarray) for x in xs):
         return np.stack(xs)
     return jnp.stack(xs)
+
+
+def _stack_step_axis(batch_stacks):
+    """The (T, G, ...) step-major stacks of a group's per-step batches,
+    stacked on the host as the span ``fl.stack``."""
+    if not batch_stacks:
+        return ()
+    with tracing.span("fl.stack"):
+        return jax.tree.map(lambda *xs: _stack_steps(xs), *batch_stacks)
 
 
 class LocalTrainer:
@@ -102,24 +112,30 @@ class LocalTrainer:
     def _make_raw_step_scaled(self) -> Callable:
         """Like ``_make_raw_step`` but at static ``lora_rank=r_max`` with a
         TRACED per-client ``lora_scale`` -- the all-rank masked runner vmaps
-        over it."""
+        over it. Named scopes mark the step's stages in the trace: under
+        ``client.grad``, the forward as ``jvp(client.forward)`` and the
+        backward as ``transpose(jvp(client.forward))``; then the optimizer
+        update, ``client.update``."""
         model, opt = self.model, self.opt
         r_max = model.lora.r_max
 
         def loss_fn(lora, base, batch, scale):
-            params = merge_lora(base, lora)
-            loss, metrics = model.train_loss(params, batch, lora_rank=r_max,
-                                             lora_scale=scale)
+            with jax.named_scope("client.forward"):
+                params = merge_lora(base, lora)
+                loss, metrics = model.train_loss(
+                    params, batch, lora_rank=r_max, lora_scale=scale)
             return loss, metrics
 
         freeze_a = self.freeze_a
 
         def step(lora, opt_state, base, batch, lr, scale):
-            (loss, metrics), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(lora, base, batch, scale)
-            if freeze_a:
-                grads = self._zero_frozen(grads)
-            lora, opt_state = opt.update(grads, opt_state, lora, lr)
+            with jax.named_scope("client.grad"):
+                (loss, metrics), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(lora, base, batch, scale)
+            with jax.named_scope("client.update"):
+                if freeze_a:
+                    grads = self._zero_frozen(grads)
+                lora, opt_state = opt.update(grads, opt_state, lora, lr)
             return lora, opt_state, metrics
 
         return step
@@ -303,8 +319,7 @@ class LocalTrainer:
         scales = np.asarray([self.model.lora.scaling(int(r))
                              for r in ranks], np.float32)
         runner = self.masked_runner(len(batch_stacks))
-        stacks = (jax.tree.map(lambda *xs: _stack_steps(xs), *batch_stacks)
-                  if batch_stacks else ())
+        stacks = _stack_step_axis(batch_stacks)
         return runner(global_lora, base, stacks, np.float32(lr),
                       mask, scales)
 
@@ -329,7 +344,6 @@ class LocalTrainer:
         scales = np.asarray([self.model.lora.scaling(int(r))
                              for r in ranks], np.float32)
         runner = self.masked_runner_sharded(len(batch_stacks), mesh)
-        stacks = (jax.tree.map(lambda *xs: _stack_steps(xs), *batch_stacks)
-                  if batch_stacks else ())
+        stacks = _stack_step_axis(batch_stacks)
         return runner(global_lora, base, stacks, np.float32(lr),
                       mask, scales)

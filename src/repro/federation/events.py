@@ -48,7 +48,7 @@ from typing import Callable, Dict, Iterator, List, Optional, Sequence, Set
 
 import numpy as np
 
-from repro.analysis import host_cost
+from repro import tracing
 from repro.data.traces import TraceRecord
 
 
@@ -467,14 +467,14 @@ class EventScheduler:
         # O(num_clients) pool scan -- only on the lifecycle-event slow
         # path; the host-cost registry contract rides on the None fast
         # path above staying the common case
-        host_cost.tick("events/active_scan", num_clients)
+        tracing.count("events/active_scan", num_clients)
         pool = np.array([c for c in range(num_clients)
                          if c not in self._inactive], dtype=np.int64)
         assert pool.size > 0, "every client has dropped out"
         return pool
 
     def dispatch(self, plan_round: int, clients: Sequence[int]) -> None:
-        host_cost.tick("events/dispatch", len(clients))
+        tracing.count("events/dispatch", len(clients))
         t = self.clock.now
         self._book[plan_round] = {"size": len(clients), "arrived": {},
                                   "consumed": set(), "dropped": set()}
@@ -511,7 +511,7 @@ class EventScheduler:
         """{plan_round: {member: arrival_time}} of every buffered update,
         marking them consumed. Called by the aggregation at a fire."""
         out: Dict[int, Dict[int, float]] = {}
-        host_cost.tick("events/book_scan", len(self._book))
+        tracing.count("events/book_scan", len(self._book))
         # explicit client-iteration order: ascending plan round, ascending
         # member within a plan -- the aggregation's client axis (and thus
         # the fire log and the consumed bookkeeping) must not depend on
@@ -523,7 +523,7 @@ class EventScheduler:
             if ready:
                 out[pr] = ready
                 b["consumed"].update(ready)
-                host_cost.tick("events/ready", len(ready))
+                tracing.count("events/ready", len(ready))
         if out:
             stal = max(self.staleness_of(self.clock.now, t)
                        for rd in out.values() for t in rd.values())

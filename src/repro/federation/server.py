@@ -68,6 +68,12 @@ Two round engines (DESIGN.md "Batched round engine"):
   pool between rounds. The count trigger under the unit-latency trace is
   bit-equal to the ``pipeline_depth=k`` cadence path
   (tests/test_events.py).
+
+Every engine's stages are spans of ``repro.tracing``: ``fl.round`` holds
+``fl.plan`` (sampling and the data pipeline), ``fl.train`` (host stacking,
+``fl.stack``, and the training dispatch), ``fl.aggregate``, ``fl.write``
+(the write-back dispatch) and ``fl.sync`` (the host reading losses and
+probes back).
 """
 from __future__ import annotations
 
@@ -83,7 +89,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.analysis import host_cost
+from repro import tracing
 from repro.configs.base import FLConfig, LoRAConfig
 from repro.core.aggregation import Aggregator, cohort_weights, weighted_avg
 from repro.core.energy import EnergyTrace
@@ -371,30 +377,32 @@ class FederatedLoRA:
 
         ``BucketedUpdate`` (grouped engines) writes in ONE jitted dispatch;
         a per-adapter dict (sequential reference) writes eagerly."""
-        if isinstance(results, BucketedUpdate):
-            self.global_lora = _write_bucketed(
-                self.global_lora,
-                tuple((b, a) for _, b, a in results.buckets),
-                results.mags,
-                bucket_parents=tuple(parents
-                                     for parents, _, _ in results.buckets))
-        else:
-            from repro.core.lora import _is_lora_path
+        with tracing.span("fl.write"):
+            if isinstance(results, BucketedUpdate):
+                self.global_lora = _write_bucketed(
+                    self.global_lora,
+                    tuple((b, a) for _, b, a in results.buckets),
+                    results.mags,
+                    bucket_parents=tuple(
+                        parents for parents, _, _ in results.buckets))
+            else:
+                from repro.core.lora import _is_lora_path
 
-            def rebuild(path, x):
-                if x is None or not _is_lora_path(path):
-                    return x
-                parent = tuple(str(getattr(p, "key", p)) for p in path[:-1])
-                if path[-1].key == "lora_m":
-                    m_new = results.get((parent, "m"))
-                    return x if m_new is None else m_new.astype(x.dtype)
-                b_g, a_g = results[parent]
-                if path[-1].key == "lora_a":
-                    return jnp.swapaxes(b_g, -2, -1).astype(x.dtype)
-                return jnp.swapaxes(a_g, -2, -1).astype(x.dtype)
+                def rebuild(path, x):
+                    if x is None or not _is_lora_path(path):
+                        return x
+                    parent = tuple(str(getattr(p, "key", p))
+                                   for p in path[:-1])
+                    if path[-1].key == "lora_m":
+                        m_new = results.get((parent, "m"))
+                        return x if m_new is None else m_new.astype(x.dtype)
+                    b_g, a_g = results[parent]
+                    if path[-1].key == "lora_a":
+                        return jnp.swapaxes(b_g, -2, -1).astype(x.dtype)
+                    return jnp.swapaxes(a_g, -2, -1).astype(x.dtype)
 
-            self.global_lora = jax.tree_util.tree_map_with_path(
-                rebuild, self.global_lora, is_leaf=lambda x: x is None)
+                self.global_lora = jax.tree_util.tree_map_with_path(
+                    rebuild, self.global_lora, is_leaf=lambda x: x is None)
         # round landing: bump the serving adapter version and notify
         # subscribers (AdapterStore hot-swap) with the new global factors.
         # Hooks degrade to skip-and-warn: a run whose adapters are not
@@ -483,14 +491,14 @@ class FederatedLoRA:
         groups: Dict[int, List[int]] = {}
         for i, batches in enumerate(client_batches):
             groups.setdefault(len(batches), []).append(i)
-        host_cost.tick("server/train_groups", len(groups))
+        tracing.count("server/train_groups", len(groups))
         group_factors = []
         loss_parts = []
         r_max = self.lora_cfg.r_max
         r_min = min(self.lora_cfg.rank_levels)
         for steps, idxs in sorted(groups.items()):
             members = idxs
-            host_cost.tick("server/train_stack_steps", steps * len(idxs))
+            tracing.count("server/train_stack_steps", steps * len(idxs))
             if sharded:
                 n_shards = self.mesh.shape["data"]
                 members = idxs + [-1] * ((-len(idxs)) % n_shards)
@@ -505,11 +513,12 @@ class FederatedLoRA:
             # synchronize with in-flight device work on the CPU client and
             # break the async engine's overlap; the training dispatch
             # transfers the stacked batches
-            stacks = [
-                jax.tree.map(lambda *xs: _stack_steps(xs),
-                             *[client_batches[i if i >= 0 else idxs[0]][t]
-                               for i in members])
-                for t in range(steps)]
+            with tracing.span("fl.stack"):
+                stacks = [
+                    jax.tree.map(lambda *xs: _stack_steps(xs),
+                                 *[client_batches[i if i >= 0 else idxs[0]][t]
+                                   for i in members])
+                    for t in range(steps)]
             lora_g, loss_g = self.trainer.dispatch_group_masked(
                 self.base, self.global_lora, g_ranks, stacks, lr,
                 mesh=self.mesh if sharded else None)
@@ -618,7 +627,7 @@ class FederatedLoRA:
         # group-order permutation of the client axis (ghosts: rank r_min,
         # zero samples, zero staleness, never present)
         members = [i for mem, _, _ in group_factors for i in mem]
-        host_cost.tick("server/agg_members", len(members))
+        tracing.count("server/agg_members", len(members))
         ranks_o, n_k_o, stal_o, pres_o = flatten_cohort(
             members, ranks, n_k, staleness, present, r_min)
         w_clients = jnp.asarray(cohort_weights(n_k_o, stal_o, pres_o, gamma))
@@ -633,7 +642,7 @@ class FederatedLoRA:
                 continue
             gb0, ga0 = global_factors[parent]
             buckets.setdefault((gb0.shape, ga0.shape), []).append(parent)
-        host_cost.tick("server/agg_buckets", len(buckets))
+        tracing.count("server/agg_buckets", len(buckets))
         for group in buckets.values():
             args = (
                 [[fg[p][0] for p in group] for _, _, fg in group_factors],
@@ -725,47 +734,52 @@ class FederatedLoRA:
         pool (dropouts excluded, joined clients included); scenarios with
         no lifecycle events keep ``active=None`` and therefore the exact
         historical rng stream."""
-        fl = self.fl
-        active = (None if self.event_scheduler is None else
-                  self.event_scheduler.active_clients(
-                      self.registry.num_clients))
-        clients = self.registry.sample_round(fl.clients_per_round,
-                                             self.rng,
-                                             active=active).tolist()
-        host_cost.tick("server/plan_clients", len(clients))
-        plan = RoundPlan(
-            round=self._plan_idx, version=self.round_idx, clients=clients,
-            ranks=[int(self.registry.ranks[c]) for c in clients],
-            n_k=[max(self.registry.num_samples(c), 1) for c in clients],
-            lr=self.schedule(self._plan_idx),
-            client_batches=[self.batch_fn(cid, self.rng) for cid in clients])
-        self._plan_idx += 1
-        return plan
+        with tracing.span("fl.plan"):
+            fl = self.fl
+            active = (None if self.event_scheduler is None else
+                      self.event_scheduler.active_clients(
+                          self.registry.num_clients))
+            clients = self.registry.sample_round(fl.clients_per_round,
+                                                 self.rng,
+                                                 active=active).tolist()
+            tracing.count("server/plan_clients", len(clients))
+            plan = RoundPlan(
+                round=self._plan_idx, version=self.round_idx,
+                clients=clients,
+                ranks=[int(self.registry.ranks[c]) for c in clients],
+                n_k=[max(self.registry.num_samples(c), 1) for c in clients],
+                lr=self.schedule(self._plan_idx),
+                client_batches=[self.batch_fn(cid, self.rng)
+                                for cid in clients])
+            self._plan_idx += 1
+            return plan
 
     def _train_stage(self, plan: RoundPlan) -> None:
         """TRAIN stage: dispatch the plan's local training. Grouped engines
         are non-blocking (jax handles stay enqueued); the sequential
         reference trains eagerly."""
-        if self.round_engine == "sequential":
-            plan.client_factors, plan.losses = self._train_sequential(
-                plan.client_batches, plan.ranks, plan.lr, plan.clients)
-        else:
-            plan.group_factors, plan.loss_parts = self._train_grouped(
-                plan.client_batches, plan.ranks, plan.lr, plan.clients,
-                sharded=self._sharded_dispatch)
+        with tracing.span("fl.train", clients=len(plan.clients)):
+            if self.round_engine == "sequential":
+                plan.client_factors, plan.losses = self._train_sequential(
+                    plan.client_batches, plan.ranks, plan.lr, plan.clients)
+            else:
+                plan.group_factors, plan.loss_parts = self._train_grouped(
+                    plan.client_batches, plan.ranks, plan.lr, plan.clients,
+                    sharded=self._sharded_dispatch)
         plan.client_batches = None     # free the host-side batch copies
 
     def _aggregate_stage(self, plan: RoundPlan, staleness: int = 0):
         """AGGREGATE stage: bucketed aggregation + SVD realloc (+ bucketed
         server momentum) of one trained plan against the CURRENT global
         adapters, discounting by the plan's staleness."""
-        if self.round_engine == "sequential":
-            return self._aggregate_sequential(plan.client_factors,
-                                              plan.ranks, plan.n_k)
-        return self._aggregate_grouped(
-            plan.group_factors, plan.ranks, plan.n_k,
-            sharded=self._sharded_dispatch,
-            staleness=[staleness] * len(plan.clients))
+        with tracing.span("fl.aggregate"):
+            if self.round_engine == "sequential":
+                return self._aggregate_sequential(plan.client_factors,
+                                                  plan.ranks, plan.n_k)
+            return self._aggregate_grouped(
+                plan.group_factors, plan.ranks, plan.n_k,
+                sharded=self._sharded_dispatch,
+                staleness=[staleness] * len(plan.clients))
 
     def _finalize_round(self, plan: RoundPlan, results, deltas, sigma_probe,
                         t0: float) -> RoundStats:
@@ -817,33 +831,38 @@ class FederatedLoRA:
         fire several aggregations inside one round's window, so an entry
         may carry a LIST of probe handles -- each is recorded in the energy
         trace; the round's stats keep the last."""
-        while len(self._stat_queue) > keep:
-            stats, plan, sigma_probe = self._stat_queue.popleft()
-            probes = (sigma_probe if isinstance(sigma_probe, list)
-                      else [sigma_probe])
-            for handle in probes:
-                probe = self._materialize_probe(handle)
-                if probe is not None:
-                    self.energy.record(probe)
-                    stats.sigma_probe = probe
-            losses = (plan.losses if plan.losses is not None
-                      else self._losses_from_parts(plan.loss_parts,
-                                                   len(plan.ranks)))
-            # nanmean: a zero-batch client trains 0 steps and reports NaN --
-            # a per-client condition that must not poison the round stat
-            loss_arr = np.asarray(losses, dtype=np.float64)
-            stats.mean_client_loss = (
-                float(np.nanmean(loss_arr))
-                if not np.all(np.isnan(loss_arr)) else float("nan"))
+        with tracing.span("fl.sync"):
+            while len(self._stat_queue) > keep:
+                stats, plan, sigma_probe = self._stat_queue.popleft()
+                probes = (sigma_probe if isinstance(sigma_probe, list)
+                          else [sigma_probe])
+                for handle in probes:
+                    probe = self._materialize_probe(handle)
+                    if probe is not None:
+                        self.energy.record(probe)
+                        stats.sigma_probe = probe
+                losses = (plan.losses if plan.losses is not None
+                          else self._losses_from_parts(plan.loss_parts,
+                                                       len(plan.ranks)))
+                # nanmean: a zero-batch client trains 0 steps and reports
+                # NaN -- a per-client condition that must not poison the
+                # round stat
+                loss_arr = np.asarray(losses, dtype=np.float64)
+                stats.mean_client_loss = (
+                    float(np.nanmean(loss_arr))
+                    if not np.all(np.isnan(loss_arr)) else float("nan"))
 
     def run_round(self) -> RoundStats:
-        if self.round_engine == "async":
-            return self._run_round_async()
-        t0 = self._now()
-        plan = self._plan_round()
-        self._train_stage(plan)
-        results, deltas, sigma_probe = self._aggregate_stage(plan)
-        return self._finalize_round(plan, results, deltas, sigma_probe, t0)
+        """One round of the configured engine, as the span ``fl.round``."""
+        with tracing.span("fl.round", round=self.round_idx):
+            if self.round_engine == "async":
+                return self._run_round_async()
+            t0 = self._now()
+            plan = self._plan_round()
+            self._train_stage(plan)
+            results, deltas, sigma_probe = self._aggregate_stage(plan)
+            return self._finalize_round(plan, results, deltas, sigma_probe,
+                                        t0)
 
     def _run_round_async(self) -> RoundStats:
         """One async round: plan + dispatch this round's training
@@ -941,9 +960,10 @@ class FederatedLoRA:
                 staleness.append(
                     sched.staleness_of(fire_time, arrived[j])
                     if j in arrived else 0)
-        return self._aggregate_grouped(
-            group_factors, ranks, n_k, sharded=self._sharded_dispatch,
-            staleness=staleness, present=present)
+        with tracing.span("fl.aggregate"):
+            return self._aggregate_grouped(
+                group_factors, ranks, n_k, sharded=self._sharded_dispatch,
+                staleness=staleness, present=present)
 
     def _retire_completed(self) -> None:
         """Drop pending plans whose every member has been aggregated or
@@ -987,9 +1007,10 @@ class FederatedLoRA:
         ranks, n_k, group_factors = self._merge_plan_groups(plans)
         staleness = [as_of_round - p.round
                      for p in plans for _ in p.clients]
-        out = self._aggregate_grouped(
-            group_factors, ranks, n_k,
-            sharded=self._sharded_dispatch, staleness=staleness)
+        with tracing.span("fl.aggregate"):
+            out = self._aggregate_grouped(
+                group_factors, ranks, n_k,
+                sharded=self._sharded_dispatch, staleness=staleness)
         for p in plans:
             # consumed by the aggregation dispatch; only loss_parts are
             # still needed (stat flush) -- dropping the factor-stack refs

@@ -6,7 +6,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
-from repro.analysis import host_cost
+from repro import tracing
 from repro.configs.base import FLConfig, LoRAConfig
 
 
@@ -44,7 +44,7 @@ class ClientRegistry:
         cid = self.num_clients
         # np.append copies the whole (K,) rank vector -- an O(K) cost per
         # JOIN event (not per round); the host-cost shim records it
-        host_cost.tick("registry/add_client")
+        tracing.count("registry/add_client")
         self.ranks = np.append(self.ranks, int(rank)).astype(int)
         self.shards.append(np.asarray(shard, dtype=np.int64))
         return cid
@@ -77,10 +77,10 @@ class ClientRegistry:
         keeps the exact historical rng consumption, so scenarios without
         lifecycle events reproduce cadence-engine sampling bit-for-bit."""
         if active is None:
-            host_cost.tick("registry/sample", m)
+            tracing.count("registry/sample", m)
             return rng.choice(self.num_clients, size=m, replace=False)
         active = np.asarray(active)
-        host_cost.tick("registry/active_pool", active.size)
+        tracing.count("registry/active_pool", active.size)
         m = min(int(m), active.size)
         return active[rng.choice(active.size, size=m, replace=False)]
 

@@ -32,6 +32,7 @@ from typing import Any, Dict, Mapping, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.core.lora import _is_lora_path
 
 Pages = Any  # lora-tree-shaped pytree; leaves carry a leading page axis
@@ -139,31 +140,33 @@ class AdapterStore:
         if version <= self._version:
             raise ValueError(
                 f"version must be monotonic: {version} <= {self._version}")
-        order = [aid for lvl in self.rank_levels
-                 for aid in self.buckets()[lvl]]
-        page_of = {aid: p for p, aid in enumerate(order)}
-        ranks = tuple(self._staged[aid][0] for aid in order)
-        scales = tuple(float(self._scaling_fn(r)) for r in ranks)
-        trees = []
-        for aid in order:
-            rank, tree = self._staged[aid]
-            s = self._scaling_fn(rank)
+        with tracing.span("serve.publish", version=version):
+            order = [aid for lvl in self.rank_levels
+                     for aid in self.buckets()[lvl]]
+            page_of = {aid: p for p, aid in enumerate(order)}
+            ranks = tuple(self._staged[aid][0] for aid in order)
+            scales = tuple(float(self._scaling_fn(r)) for r in ranks)
+            trees = []
+            for aid in order:
+                rank, tree = self._staged[aid]
+                s = self._scaling_fn(rank)
 
-            def pack(path, leaf):
-                if leaf is None or not _is_lora_path(path):
+                def pack(path, leaf):
+                    if leaf is None or not _is_lora_path(path):
+                        return leaf
+                    leaf = _mask_and_pad(path, leaf, rank, self.r_max)
+                    if path[-1].key == "lora_b" and s != 1.0:
+                        # fold the per-tenant scaling into B so the
+                        # engine can run every page at unit scale
+                        leaf = leaf * jnp.asarray(s, leaf.dtype)
                     return leaf
-                leaf = _mask_and_pad(path, leaf, rank, self.r_max)
-                if path[-1].key == "lora_b" and s != 1.0:
-                    # fold the per-tenant scaling into B so the engine can
-                    # run every page at unit scale
-                    leaf = leaf * jnp.asarray(s, leaf.dtype)
-                return leaf
 
-            trees.append(jax.tree_util.tree_map_with_path(
-                pack, tree, is_leaf=lambda x: x is None))
-        pages = jax.tree.map(
-            lambda *leaves: None if leaves[0] is None else jnp.stack(leaves),
-            *trees, is_leaf=lambda x: x is None)
+                trees.append(jax.tree_util.tree_map_with_path(
+                    pack, tree, is_leaf=lambda x: x is None))
+            pages = jax.tree.map(
+                lambda *leaves: (None if leaves[0] is None
+                                 else jnp.stack(leaves)),
+                *trees, is_leaf=lambda x: x is None)
         snap = PublishedAdapters(version=version, pages=pages,
                                  page_of=page_of, ranks=ranks,
                                  scales=scales)

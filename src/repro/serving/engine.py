@@ -30,7 +30,9 @@ from typing import Any, List, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from repro import tracing
 from repro.core.lora import merge_lora, split_lora
 from repro.serving.adapter_store import AdapterStore
 
@@ -126,28 +128,38 @@ class ServingEngine:
         self.logits = None
         self.version_log: List[int] = []     # one snapshot version per step
 
+        # named scopes for the trace: the adapter page gather
+        # (``serve.pages``), the model (``serve.model``) and, in decode, the
+        # select that freezes inactive slots (``serve.select``)
         def prefill_impl(base, pages, page_ids, prompts):
-            merged = substitute_pages(base, pages, page_ids)
-            logits, caches = model.prefill(merged, {"tokens": prompts})
-            logits = logits[:, -1, :]
-            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("serve.pages"):
+                merged = substitute_pages(base, pages, page_ids)
+            with jax.named_scope("serve.model"):
+                logits, caches = model.prefill(merged, {"tokens": prompts})
+                logits = logits[:, -1, :]
+                next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return next_tok, logits, caches
 
         def decode_impl(base, pages, page_ids, tokens, cache, active):
-            merged = substitute_pages(base, pages, page_ids)
-            logits, new_cache = model.decode_step(
-                merged, {"token": tokens[:, None]}, cache)
-            logits = logits[:, -1, :]
-            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("serve.pages"):
+                merged = substitute_pages(base, pages, page_ids)
+            with jax.named_scope("serve.model"):
+                logits, new_cache = model.decode_step(
+                    merged, {"token": tokens[:, None]}, cache)
+                logits = logits[:, -1, :]
+                next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             # inactive slots are frozen: token, length and SSM states hold
-            next_tok = jnp.where(active, next_tok, tokens)
-            sel = lambda n, o: jax.tree.map(
-                lambda a, b: jnp.where(
-                    jnp.reshape(active, (1, -1) + (1,) * (a.ndim - 2)), a, b),
-                n, o)
-            new_cache["layers"] = sel(new_cache["layers"], cache["layers"])
-            new_cache["len"] = jnp.where(active, new_cache["len"],
-                                         cache["len"])
+            with jax.named_scope("serve.select"):
+                next_tok = jnp.where(active, next_tok, tokens)
+                sel = lambda n, o: jax.tree.map(
+                    lambda a, b: jnp.where(
+                        jnp.reshape(active, (1, -1) + (1,) * (a.ndim - 2)),
+                        a, b),
+                    n, o)
+                new_cache["layers"] = sel(new_cache["layers"],
+                                          cache["layers"])
+                new_cache["len"] = jnp.where(active, new_cache["len"],
+                                             cache["len"])
             return next_tok, logits, new_cache
 
         self._prefill = jax.jit(prefill_impl)
@@ -159,37 +171,51 @@ class ServingEngine:
               adapter_ids: Sequence[Any]) -> jnp.ndarray:
         """Prefill ``prompts`` ((n, L) int32) into slots ``slot_idx`` with
         per-request tenants ``adapter_ids``; returns the first greedy token
-        per admitted request. One adapter snapshot for the whole call."""
-        snap = self.store.published            # THE capture
-        self.version_log.append(snap.version)
-        slot_idx = list(slot_idx)
-        prompts = jnp.asarray(prompts, jnp.int32)
-        n, lp = prompts.shape
-        assert len(slot_idx) == n == len(list(adapter_ids))
-        # full-width prefill: inactive rows run on zeros and are discarded
-        full_prompts = jnp.zeros((self.slots, lp), jnp.int32)
-        full_prompts = full_prompts.at[jnp.asarray(slot_idx)].set(prompts)
-        new_pages = self.slot_pages.at[jnp.asarray(slot_idx)].set(
-            snap.page_ids(adapter_ids))
-        next_tok, self.logits, caches = self._prefill(
-            self.base, snap.pages, new_pages, full_prompts)
-        mask = jnp.zeros((self.slots,), bool).at[jnp.asarray(slot_idx)].set(
-            True)
-        self.cache = seed_cache(self.cache, caches, lp, mask)
-        self.tokens = jnp.where(mask, next_tok, self.tokens)
-        self.slot_pages = new_pages
-        return next_tok[jnp.asarray(slot_idx)]
+        per admitted request. One adapter snapshot for the whole call.
+        Counts the rows prefilled (``serve.prefill_rows``, every slot) and
+        the requests admitted (``serve.admitted``), inside the span
+        ``serve.engine.admit``."""
+        with tracing.span("serve.engine.admit"):
+            snap = self.store.published        # THE capture
+            self.version_log.append(snap.version)
+            slot_idx = list(slot_idx)
+            prompts = jnp.asarray(prompts, jnp.int32)
+            n, lp = prompts.shape
+            assert len(slot_idx) == n == len(list(adapter_ids))
+            tracing.count("serve.prefill_rows", self.slots)
+            tracing.count("serve.admitted", n)
+            # full-width prefill: inactive rows run on zeros and are
+            # discarded
+            idx = jnp.asarray(slot_idx)
+            full_prompts = jnp.zeros((self.slots, lp), jnp.int32)
+            full_prompts = full_prompts.at[idx].set(prompts)
+            new_pages = self.slot_pages.at[idx].set(
+                snap.page_ids(adapter_ids))
+            next_tok, self.logits, caches = self._prefill(
+                self.base, snap.pages, new_pages, full_prompts)
+            mask = jnp.zeros((self.slots,), bool).at[idx].set(True)
+            self.cache = seed_cache(self.cache, caches, lp, mask)
+            self.tokens = jnp.where(mask, next_tok, self.tokens)
+            self.slot_pages = new_pages
+            return next_tok[idx]
 
     def decode(self, active_mask) -> jnp.ndarray:
         """One greedy decode step for every active slot; returns the (S,)
-        token vector. One adapter snapshot for the whole step."""
-        snap = self.store.published            # THE capture
-        self.version_log.append(snap.version)
-        active = jnp.asarray(active_mask, bool)
-        self.tokens, self.logits, self.cache = self._decode(
-            self.base, snap.pages, self.slot_pages, self.tokens, self.cache,
-            active)
-        return self.tokens
+        token vector. One adapter snapshot for the whole step. Counts the
+        rows decoded (``serve.decode_rows``, every slot) and the live ones
+        (``serve.decode_live``, read from the mask as given), inside the
+        span ``serve.engine.decode``."""
+        with tracing.span("serve.engine.decode"):
+            tracing.count("serve.decode_rows", self.slots)
+            tracing.count("serve.decode_live",
+                          np.count_nonzero(np.asarray(active_mask)))
+            snap = self.store.published        # THE capture
+            self.version_log.append(snap.version)
+            active = jnp.asarray(active_mask, bool)
+            self.tokens, self.logits, self.cache = self._decode(
+                self.base, snap.pages, self.slot_pages, self.tokens,
+                self.cache, active)
+            return self.tokens
 
     # -- introspection -------------------------------------------------------
 

@@ -28,6 +28,7 @@ from typing import Any, Deque, Dict, List, Optional
 
 import numpy as np
 
+from repro import tracing
 from repro.federation.events import LatencyModel, VirtualClock
 
 
@@ -77,54 +78,75 @@ class ContinuousBatcher:
     # -- one scheduler step ---------------------------------------------------
 
     def step(self) -> None:
-        """Admit into free slots, then decode every active slot once."""
-        free = [i for i, r in enumerate(self.slots) if r is None]
-        admits: List[ServeRequest] = []
-        idxs: List[int] = []
-        while free and self.queue and self.queue[0].arrival <= self.clock.now:
-            req = self.queue.popleft()
-            slot = free.pop(0)
-            self.slots[slot] = req
-            admits.append(req)
-            idxs.append(slot)
-        cost = 0.0
-        if admits:
-            first = self.engine.admit(
-                idxs, np.stack([np.asarray(r.prompt) for r in admits]),
-                [r.adapter_id for r in admits])
-            for r, tok in zip(admits, np.asarray(first)):
-                r.t_admit = self.clock.now
-                r.t_first = self.clock.now   # refined after the charge below
-                r.tokens.append(int(tok))
-            cost += self.prefill_cost
-        active = np.asarray([r is not None for r in self.slots], bool)
-        if active.any():
-            # skip slots whose request completed with the prefill token
-            decode_mask = active.copy()
+        """Admit into free slots, then decode every active slot once.
+
+        The step is the span ``serve.step``; inside it, the calls into the
+        engine are ``serve.admit`` and ``serve.decode``, and the host's
+        waits for their tokens ``serve.wait``. Each request's wait for a
+        slot, on the batcher's clock, is the sample ``serve.queue_wait_s``.
+        """
+        with tracing.span("serve.step"):
+            free = [i for i, r in enumerate(self.slots) if r is None]
+            admits: List[ServeRequest] = []
+            idxs: List[int] = []
+            while (free and self.queue
+                   and self.queue[0].arrival <= self.clock.now):
+                req = self.queue.popleft()
+                slot = free.pop(0)
+                self.slots[slot] = req
+                req.t_admit = self.clock.now
+                tracing.observe("serve.queue_wait_s",
+                                req.t_admit - req.arrival, rid=req.rid)
+                admits.append(req)
+                idxs.append(slot)
+            cost = 0.0
+            if admits:
+                with tracing.span("serve.admit",
+                                  rids=[r.rid for r in admits],
+                                  n=len(admits)):
+                    first = self.engine.admit(
+                        idxs,
+                        np.stack([np.asarray(r.prompt) for r in admits]),
+                        [r.adapter_id for r in admits])
+                with tracing.span("serve.wait"):
+                    first = np.asarray(first)
+                for r, tok in zip(admits, first):
+                    # refined after the charge below
+                    r.t_first = self.clock.now
+                    r.tokens.append(int(tok))
+                cost += self.prefill_cost
+            active = np.asarray([r is not None for r in self.slots], bool)
+            if active.any():
+                # skip slots whose request completed with the prefill token
+                decode_mask = active.copy()
+                for i, r in enumerate(self.slots):
+                    if r is not None and self._finished(r):
+                        decode_mask[i] = False
+                if decode_mask.any():
+                    with tracing.span("serve.decode",
+                                      live=int(decode_mask.sum())):
+                        toks = self.engine.decode(decode_mask)
+                    with tracing.span("serve.wait"):
+                        toks = np.asarray(toks)
+                    for i, r in enumerate(self.slots):
+                        if r is not None and decode_mask[i]:
+                            r.tokens.append(int(toks[i]))
+                cost += self.step_cost
+                if self.latency is not None:
+                    draws = [self.latency.sample(self._client_of(r))
+                             for r in self.slots if r is not None]
+                    cost += max(draws)
+            if cost:
+                self.clock.advance(self.clock.now + cost)
+            for r in admits:
+                r.t_first = self.clock.now
+            # evict finished requests so their slots recycle next step
             for i, r in enumerate(self.slots):
                 if r is not None and self._finished(r):
-                    decode_mask[i] = False
-            if decode_mask.any():
-                toks = np.asarray(self.engine.decode(decode_mask))
-                for i, r in enumerate(self.slots):
-                    if r is not None and decode_mask[i]:
-                        r.tokens.append(int(toks[i]))
-            cost += self.step_cost
-            if self.latency is not None:
-                draws = [self.latency.sample(self._client_of(r))
-                         for r in self.slots if r is not None]
-                cost += max(draws)
-        if cost:
-            self.clock.advance(self.clock.now + cost)
-        for r in admits:
-            r.t_first = self.clock.now
-        # evict finished requests so their slots recycle next step
-        for i, r in enumerate(self.slots):
-            if r is not None and self._finished(r):
-                r.t_done = self.clock.now
-                self.done.append(r)
-                self.slots[i] = None
-        self.steps += 1
+                    r.t_done = self.clock.now
+                    self.done.append(r)
+                    self.slots[i] = None
+            self.steps += 1
 
     def _client_of(self, req: ServeRequest) -> int:
         # process-independent (built-in hash() is salted): virtual stats

@@ -9,22 +9,31 @@ and pinned here as a plain assertion).
 import numpy as np
 import pytest
 
+from repro import tracing
 from repro.analysis import host_cost
 from repro.analysis.host_cost import HostCostMonitor, measure_rounds
 
 
 class TestShim:
     def test_inactive_hooks_are_noops(self):
-        host_cost.tick("nobody/listening", 100)
+        """Counts made outside a monitor -- before it opens and after it
+        closes -- are not its loop counts; only the deltas inside it are.
+        Allocations are recorded only while it is open."""
+        tracing.count("nobody/listening", 100)
         host_cost.alloc("nobody/listening", 1 << 20)
         mon = HostCostMonitor()
         assert mon.total_loop_iters == 0
         assert mon.total_alloc_bytes == 0
+        with mon:
+            tracing.count("nobody/listening", 3)
+        tracing.count("nobody/listening", 100)
+        assert mon.loop_iters == {"nobody/listening": 3}
+        assert mon.total_alloc_bytes == 0
 
     def test_tick_and_alloc_accumulate_under_monitor(self):
         with HostCostMonitor() as mon:
-            host_cost.tick("loop/a", 5)
-            host_cost.tick("loop/a", 3)
+            tracing.count("loop/a", 5)
+            tracing.count("loop/a", 3)
             host_cost.alloc("buf", 64)
         assert mon.loop_iters == {"loop/a": 8}
         assert mon.alloc_bytes == {"buf": 64}
@@ -43,9 +52,9 @@ class TestShim:
 
     def test_mark_isolates_phase_deltas(self):
         with HostCostMonitor() as mon:
-            host_cost.tick("x", 2)
+            tracing.count("x", 2)
             mon.mark("round0")
-            host_cost.tick("x", 7)
+            tracing.count("x", 7)
             host_cost.alloc("y", 10)
             mon.mark("round1")
         p0, p1 = mon.phases
@@ -62,7 +71,7 @@ class TestShim:
 
 class TestRegistryHooks:
     def test_sample_round_preserves_rng_stream(self):
-        """The tick hook must not consume rng draws: sampling through the
+        """The loop counter must not consume rng draws: sampling through the
         instrumented registry is bit-exact with a direct rng.choice."""
         from repro.configs.base import FLConfig, LoRAConfig
         from repro.federation.topology import ClientRegistry

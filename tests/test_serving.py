@@ -414,6 +414,40 @@ def test_scheduler_latency_draws_are_deterministic(attn_setup):
     assert run_once() == run_once()
 
 
+def test_t_admit_stamped_when_the_slot_is_taken(attn_setup):
+    """``t_admit`` is stamped when a request takes its slot, before the
+    engine's admit call: on a clock that moves while the call runs, it
+    lies before ``t_first``, and no request is stamped before it
+    arrived."""
+    from repro.federation.events import VirtualClock
+    cfg, model, params = attn_setup
+    _, lora_tree = split_lora(params)
+    store = AdapterStore(LORA.rank_levels)
+    store.put("t", _rand_lora(lora_tree, jax.random.PRNGKey(31)), 8)
+    store.publish()
+    engine = ServingEngine(model, params, store, max_len=12, slots=2)
+    clock = VirtualClock()
+    admit = engine.admit
+
+    def slow_admit(*args):
+        clock.advance(clock.now + 0.5)   # time passes inside the call
+        return admit(*args)
+
+    engine.admit = slow_admit
+    batcher = ContinuousBatcher(engine, clock=clock, step_cost=0.0,
+                                prefill_cost=0.0)
+    rng = np.random.default_rng(32)
+    for i in range(3):
+        batcher.submit(ServeRequest(
+            rid=i, prompt=rng.integers(0, cfg.vocab_size, size=8),
+            adapter_id="t", max_new_tokens=2, arrival=0.1 * i))
+    batcher.run()
+    assert len(batcher.done) == 3
+    for r in batcher.done:
+        assert r.arrival <= r.t_admit < r.t_first <= r.t_done
+        assert r.t_first - r.t_admit >= 0.5
+
+
 # ---------------------------------------------------------------------------
 # federation round-landing hook
 # ---------------------------------------------------------------------------

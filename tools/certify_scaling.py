@@ -211,6 +211,7 @@ def _measure_host_rows(fast: bool, verbose: bool):
 # -- controls ---------------------------------------------------------------
 
 def _add_controls(report, rows):
+    from repro import tracing
     from repro.analysis import complexity, host_cost
     from repro.analysis.complexity import Measurement, ScalingRow
 
@@ -233,7 +234,7 @@ def _add_controls(report, rows):
         for k in HOST_K_LADDER:
             with host_cost.HostCostMonitor() as mon:
                 # the injected regression: a per-round O(registry) scan
-                host_cost.tick("control/registry_scan", k)
+                tracing.count("control/registry_scan", k)
                 host_cost.alloc("control/pool_copy", 8 * k)
                 mon.mark("round0")
             ph = mon.phases[0]
