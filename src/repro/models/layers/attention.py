@@ -3,8 +3,9 @@
 TPU adaptation notes (DESIGN.md §4): rather than materializing (Lq, Lkv)
 score matrices -- which at prefill_32k would be terabytes -- we stream KV
 blocks through an online-softmax ``lax.scan``, the standard TPU formulation
-(compute lives in MXU matmuls; running max/denominator live in VREGs). The
-same code path serves:
+(compute lives in MXU matmuls; running max/denominator live in VREGs).
+Sequences of at most ``ONE_TILE_MAX`` positions, whose scores fit
+comfortably, run as one tile instead. The same code serves:
 
   * full causal attention          (train / prefill)
   * sliding-window causal          (long-context variants, hymba, llama4)
@@ -15,13 +16,22 @@ GQA/MQA is handled by grouping query heads over shared KV heads.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
+
 NEG_INF = -1e30
+
+# Sequences of at most this many query and key positions take one tile.
+# Its f32 score tensor is b*h*lq*lkv*4 bytes: 5 vmapped clients x 32
+# items x 12 heads at 197 tokens is 298 MB a layer (ViT rounds), 16 slots
+# x 32 heads at 256 is 134 MB (Granite prefill), and 16 x 32 heads at 512
+# is 537 MB. Longer sequences stream tiles to bound it; below the bound
+# tiling only adds loop overhead, padding and quarter-filled MXU tiles.
+ONE_TILE_MAX = 512
 
 
 def _gqa_group(q: jnp.ndarray, num_kv_heads: int) -> jnp.ndarray:
@@ -30,21 +40,73 @@ def _gqa_group(q: jnp.ndarray, num_kv_heads: int) -> jnp.ndarray:
     return q.reshape(b, l, num_kv_heads, h // num_kv_heads, d)
 
 
-def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                        causal: bool, sliding_window=0,
-                        q_offset: int = 0,
-                        block_q: int = 1024, block_kv: int = 1024,
-                        softcap: float = 0.0,
-                        bf16_scores: bool = False) -> jnp.ndarray:
-    """Online-softmax attention.
-
-    q: (B, Lq, H, D); k, v: (B, Lkv, KVH, D). Returns (B, Lq, H, D).
-    ``q_offset`` is the absolute position of q[0] (prefill continuation /
-    decode). ``sliding_window``: 0/None = unlimited; may be a traced scalar
-    (per-layer global-vs-window selection under lax.scan).
-    """
-    use_window = sliding_window is not None and not (
+def _uses_window(sliding_window) -> bool:
+    return sliding_window is not None and not (
         isinstance(sliding_window, int) and sliding_window == 0)
+
+
+def _masked_scores(q_blk, k_blk, q_pos, kv_pos, *, scale, causal: bool,
+                   sliding_window, softcap: float, bf16_scores: bool,
+                   kv_len: Optional[int] = None):
+    """f32 scores (B, KVH, G, q, k) of a q tile (B, KVH, G, q, D) against a
+    kv tile (B, KVH, k, D), soft-capped and masked to ``NEG_INF``.
+
+    ``q_pos``/``kv_pos`` are the tiles' absolute positions; ``kv_len``
+    masks kv padding at and beyond it."""
+    # inputs stay bf16 (collectives/copies move half the bytes); the MXU
+    # accumulates in f32 via preferred_element_type. bf16_scores: emit the
+    # dot in bf16 so its VJP dots are bf16 too -- an f32 dot here poisons
+    # every backward collective upstream (§Perf; the Pallas kernel is the
+    # lossless fix).
+    if bf16_scores:
+        s = jnp.einsum("bhgqd,bhkd->bhgqk", q_blk,
+                       k_blk).astype(jnp.float32) * scale
+    else:
+        s = jnp.einsum("bhgqd,bhkd->bhgqk", q_blk, k_blk,
+                       preferred_element_type=jnp.float32) * scale
+    if softcap > 0.0:
+        s = softcap * jnp.tanh(s / softcap)
+    mask = jnp.ones((q_pos.shape[0], kv_pos.shape[0]), dtype=bool)
+    if causal:
+        mask &= kv_pos[None, :] <= q_pos[:, None]
+    if _uses_window(sliding_window):
+        mask &= kv_pos[None, :] > q_pos[:, None] - sliding_window
+    if kv_len is not None:
+        mask &= (kv_pos < kv_len)[None, :]
+    return jnp.where(mask, s, NEG_INF)
+
+
+def _one_tile_attention(q, k, v, *, causal: bool, sliding_window=0,
+                        q_offset: int = 0, softcap: float = 0.0,
+                        bf16_scores: bool = False) -> jnp.ndarray:
+    """Attention over the whole sequence as one tile: one score product,
+    one softmax, one PV product; no scan, no padding. Arguments and
+    result as ``blockwise_attention``'s, less the block sizes."""
+    b, lq, h, d = q.shape
+    _, lkv, kvh, _ = k.shape
+    qg = _gqa_group(q, kvh).transpose(0, 2, 3, 1, 4)   # (B, KVH, G, Lq, D)
+    kt = k.transpose(0, 2, 1, 3)                       # (B, KVH, Lkv, D)
+    vt = v.transpose(0, 2, 1, 3)
+    s = _masked_scores(qg, kt, q_offset + jnp.arange(lq), jnp.arange(lkv),
+                       scale=d ** -0.5, causal=causal,
+                       sliding_window=sliding_window, softcap=softcap,
+                       bf16_scores=bf16_scores)
+    p = jnp.exp(s - s.max(axis=-1, keepdims=True))
+    denom = p.sum(axis=-1)
+    # p in the compute dtype for the MXU; f32 accumulator
+    out = jnp.einsum("bhgqk,bhkd->bhgqd", p.astype(vt.dtype), vt,
+                     preferred_element_type=jnp.float32)
+    out = (out / jnp.maximum(denom[..., None], 1e-30)).astype(q.dtype)
+    # (B, KVH, G, Lq, D) -> (B, Lq, H, D)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, lq, h, d)
+
+
+def _tiled_attention(q, k, v, *, causal: bool, sliding_window=0,
+                     q_offset: int = 0, block_q: int = 1024,
+                     block_kv: int = 1024, softcap: float = 0.0,
+                     bf16_scores: bool = False) -> jnp.ndarray:
+    """Online-softmax attention streaming (block_q, block_kv) tiles through
+    a nested ``lax.scan``; sequences are padded to block multiples."""
     b, lq, h, d = q.shape
     _, lkv, kvh, _ = k.shape
     scale = d ** -0.5
@@ -80,28 +142,11 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         def kv_block_body(carry, kvi):
             acc, m, denom = carry
             k_blk, v_blk, kv_idx = kvi
-            kv_pos = kv_idx + jnp.arange(block_kv)
-            # inputs stay bf16 (collectives/copies move half the bytes);
-            # the MXU accumulates in f32 via preferred_element_type.
-            # bf16_scores: emit the dot in bf16 so its VJP dots are bf16
-            # too -- an f32 dot here poisons every backward collective
-            # upstream (§Perf; the Pallas kernel is the lossless fix).
-            if bf16_scores:
-                s = jnp.einsum("bhgqd,bhkd->bhgqk", q_blk,
-                               k_blk).astype(jnp.float32) * scale
-            else:
-                s = jnp.einsum("bhgqd,bhkd->bhgqk", q_blk, k_blk,
-                               preferred_element_type=jnp.float32) * scale
-            if softcap > 0.0:
-                s = softcap * jnp.tanh(s / softcap)
-            mask = jnp.ones((block_q, block_kv), dtype=bool)
-            if causal:
-                mask &= kv_pos[None, :] <= q_pos[:, None]
-            if use_window:
-                mask &= kv_pos[None, :] > q_pos[:, None] - sliding_window
-            # mask out kv padding
-            mask &= (kv_pos < lkv)[None, :]
-            s = jnp.where(mask, s, NEG_INF)
+            s = _masked_scores(q_blk, k_blk, q_pos,
+                               kv_idx + jnp.arange(block_kv), scale=scale,
+                               causal=causal, sliding_window=sliding_window,
+                               softcap=softcap, bf16_scores=bf16_scores,
+                               kv_len=lkv if pad_kv else None)
             m_new = jnp.maximum(m, s.max(axis=-1))
             p = jnp.exp(s - m_new[..., None])
             alpha = jnp.exp(m - m_new)
@@ -124,6 +169,31 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     # out: (nq, B, KVH, G, bq, D) -> (B, Lq, H, D)
     out = out.transpose(1, 0, 4, 2, 3, 5).reshape(b, nq * block_q, h, d)
     return out[:, :lq]
+
+
+def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
+                        causal: bool, sliding_window=0,
+                        q_offset: int = 0,
+                        block_q: int = 1024, block_kv: int = 1024,
+                        softcap: float = 0.0,
+                        bf16_scores: bool = False) -> jnp.ndarray:
+    """Softmax attention, as one tile when both lengths are at most
+    ``ONE_TILE_MAX``, else online-softmax over (block_q, block_kv) tiles.
+
+    q: (B, Lq, H, D); k, v: (B, Lkv, KVH, D). Returns (B, Lq, H, D).
+    ``q_offset`` is the absolute position of q[0] (prefill continuation /
+    decode). ``sliding_window``: 0/None = unlimited; may be a traced scalar
+    (per-layer global-vs-window selection under lax.scan). Each trace
+    counts the path it took (``attn.one_tile`` / ``attn.tiled``).
+    """
+    kw = dict(causal=causal, sliding_window=sliding_window,
+              q_offset=q_offset, softcap=softcap, bf16_scores=bf16_scores)
+    if max(q.shape[1], k.shape[1]) <= ONE_TILE_MAX:
+        tracing.count("attn.one_tile")
+        return _one_tile_attention(q, k, v, **kw)
+    tracing.count("attn.tiled")
+    return _tiled_attention(q, k, v, block_q=block_q, block_kv=block_kv,
+                            **kw)
 
 
 def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,

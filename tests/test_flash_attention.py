@@ -48,15 +48,16 @@ class TestFlashAttention:
                                    atol=2e-5, rtol=1e-4)
 
     def test_matches_model_blockwise_path(self):
-        """The kernel and the model's lax.scan blockwise attention agree."""
-        from repro.models.layers.attention import blockwise_attention
+        """The kernel and the model's lax.scan blockwise attention agree
+        (the tiled path: at 64 tokens the model itself takes one tile)."""
+        from repro.models.layers.attention import _tiled_attention
         key = jax.random.PRNGKey(11)
         ks = jax.random.split(key, 3)
         q = jax.random.normal(ks[0], (2, 64, 8, 32))
         k = jax.random.normal(ks[1], (2, 64, 2, 32))
         v = jax.random.normal(ks[2], (2, 64, 2, 32))
         got = ops.flash_attention(q, k, v, causal=True)
-        want = blockwise_attention(q, k, v, causal=True, block_q=16,
-                                   block_kv=16)
+        want = _tiled_attention(q, k, v, causal=True, block_q=16,
+                                block_kv=16)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=1e-4)
